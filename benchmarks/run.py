@@ -61,10 +61,11 @@ Output: ``name,us_per_call,derived`` CSV on stdout plus ``BENCH_sim.json``
 grid-of-1 parity bits, sweep executable-cache hit/miss counts) at the repo
 root, so engine performance is tracked across PRs.
 
-The harness enables JAX's *persistent* compilation cache (on-disk, under
-``.jax_cache/`` at the repo root) so back-to-back benchmark runs skip warm
-compiles entirely; ``--no-persistent-cache`` turns it off for clean-compile
-measurements.
+The harness enables JAX's *persistent* compilation cache
+(``repro.utils.compile_cache``: ``$JAX_COMPILATION_CACHE_DIR`` when set,
+else ``.jax_cache/`` at the repo root) so back-to-back benchmark runs skip
+warm compiles entirely; ``--no-persistent-cache`` turns it off for
+clean-compile measurements.
 
 ``--quick`` shrinks every figure (T=500, few seeds, short FL run) for CI
 smoke coverage.
@@ -81,28 +82,15 @@ import time
 
 import jax
 
+from repro.utils.compile_cache import enable_compile_cache
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-
-def _enable_persistent_cache() -> bool:
-    """Point JAX's persistent compilation cache at ``.jax_cache/`` so a
-    second benchmark run deserializes executables instead of re-lowering
-    (works on CPU too since jax 0.4.3x).  Must run before the FIRST compile
-    of the process — the backend latches the cache decision at first use —
-    hence module-import time, ahead of the module-level ``PRNGKey``.
-    Returns False when the running jax has no persistent-cache support."""
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(ROOT, ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        return True
-    except Exception:
-        return False
-
-
+# must run before the FIRST compile of the process (JAX latches the cache
+# decision at first use) — hence module-import time, ahead of the
+# module-level ``PRNGKey``
 PERSISTENT_CACHE = ("--no-persistent-cache" not in sys.argv
-                    and _enable_persistent_cache())
+                    and enable_compile_cache())
 
 import jax.numpy as jnp
 import numpy as np
@@ -1539,7 +1527,8 @@ def kernels():
     q = jax.random.normal(KEY, (1, 4, 512, 128), jnp.float32)
     k = jax.random.normal(jax.random.fold_in(KEY, 1), (1, 2, 512, 128))
     v = jax.random.normal(jax.random.fold_in(KEY, 2), (1, 2, 512, 128))
-    _, us_k = _timed(lambda: ops.flash_attention(q, k, v, causal=True))
+    _, us_k = _timed(lambda: ops.flash_attention(
+        q, k, v, causal=True, backend="pallas_interpret"))
     _, us_r = _timed(lambda: ref.mha_attention(q, k, v, causal=True))
     row("kernel/flash_attention/pallas-interp", us_k, f"ref_us={us_r:.0f}")
 
@@ -1585,8 +1574,10 @@ def main() -> None:
 
     print("name,us_per_call,derived")
     BENCH["quick"] = QUICK
-    BENCH["backend"] = jax.default_backend()
-    BENCH["persistent_compilation_cache"] = PERSISTENT_CACHE
+    dev = jax.devices()[0]
+    BENCH["device"] = {"platform": dev.platform, "kind": dev.device_kind,
+                       "count": len(jax.devices())}
+    BENCH["persistent_compilation_cache"] = bool(PERSISTENT_CACHE)
     figures = ((scenario_suite, scenario_suite_glr) if args.scenarios else
                (fig2a_regret, fig2b_breakpoints, fig2c_scale, batch1_parity,
                 glr_detector, hp_grid, scenario_suite, scenario_suite_glr,

@@ -97,6 +97,27 @@ def dispatch_aggregate(aggregator, buffers, mask, zeta, n_succ):
     return aggregator.aggregate(buffers, mask, zeta, n_succ)
 
 
+def mean_local_loss(local_losses, active):
+    """The ``local_loss`` metric: mean final local loss over the clients that
+    trained this round, shared by the dense and sparse runtimes.
+
+    The isfinite guard keeps the *metric* finite even while a faulty
+    client's loss blows up (identical arithmetic on healthy rounds).  The
+    sum is a fixed pairwise tree of elementwise adds behind an optimization
+    barrier, not a reduce: the compiler picks a reduce's association per
+    program (fusion, layout), which drifted the metric by an ulp between
+    the dense and sparse programs at M = N = 20 while every summand agreed
+    bitwise."""
+    loss_ok = jnp.isfinite(local_losses).astype(jnp.float32)
+    x = jax.lax.optimization_barrier(
+        jnp.where(loss_ok > 0.5, local_losses, 0.0) * active)
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        x = jnp.concatenate([x[..., :h] + x[..., h:2 * h], x[..., 2 * h:]],
+                            axis=-1)
+    return x[..., 0] / jnp.maximum(jnp.sum(active * loss_ok), 1.0)
+
+
 class AsyncFLState(NamedTuple):
     params: Any                    # global model w_t
     buffers: jnp.ndarray           # (M, P) flattened G~_i (Eq. 6)
@@ -227,8 +248,11 @@ class AsyncFLTrainer:                          # jitted round caches per instanc
     def init(self, params: Any, key: jax.Array, hp: Any = None) -> AsyncFLState:
         m = self.cfg.n_clients
         p = int(tree_flatten_concat(params).shape[0])
+        # the state owns copies of the caller's arrays: ``run`` donates the
+        # state off-CPU, which would otherwise delete them
+        own = functools.partial(jax.tree_util.tree_map, jnp.array)
         return AsyncFLState(
-            params=params,
+            params=own(params),
             buffers=jnp.zeros((m, p), jnp.float32),
             has_update=jnp.zeros((m,), jnp.float32),
             last_success=jnp.ones((m,), jnp.float32),   # round 0: all start fresh
@@ -236,7 +260,7 @@ class AsyncFLTrainer:                          # jitted round caches per instanc
             contrib_buf=init_buffer(m, p),
             contrib=jnp.ones((m,), jnp.float32),
             zeta=jnp.full((m,), 1.0 / m),
-            sched_state=init_with_hp(self.scheduler, key, hp),
+            sched_state=init_with_hp(self.scheduler, key, own(hp)),
             matcher_state=AdaptiveMatcher(self.cfg.matcher_beta).init(),
             t=jnp.zeros((), jnp.int32),
             env_state=self.env.interact_init(),
@@ -423,15 +447,8 @@ class AsyncFLTrainer:                          # jitted round caches per instanc
             staleness=staleness,
             fault_state=fault_state,
         )
-        # losses of clients that actually trained this round; the isfinite
-        # guard keeps the *metric* finite even while a faulty client's loss
-        # blows up (identical arithmetic on healthy rounds: loss_ok == 1)
-        loss_ok = jnp.isfinite(local_losses).astype(jnp.float32)
-        loss_w = active * loss_ok
         metrics = {
-            "local_loss": jnp.sum(
-                jnp.where(loss_ok > 0.5, local_losses, 0.0) * active)
-            / jnp.maximum(jnp.sum(loss_w), 1.0),
+            "local_loss": mean_local_loss(local_losses, active),
             "n_success": n_succ,
             "mean_aoi": jnp.mean(aoi),
             "aoi_var": aoi_variance(aoi),
@@ -648,12 +665,8 @@ class AsyncFLTrainer:                          # jitted round caches per instanc
             staleness=staleness,
             fault_state=pre.fault_state,
         )
-        loss_ok = jnp.isfinite(pre.local_losses).astype(jnp.float32)
-        loss_w = pre.active * loss_ok
         metrics = {
-            "local_loss": jnp.sum(
-                jnp.where(loss_ok > 0.5, pre.local_losses, 0.0) * pre.active)
-            / jnp.maximum(jnp.sum(loss_w), 1.0),
+            "local_loss": mean_local_loss(pre.local_losses, pre.active),
             "n_success": n_succ,
             "mean_aoi": jnp.mean(aoi),
             "aoi_var": aoi_variance(aoi),

@@ -79,7 +79,7 @@ from repro.core.contribution import (
 from repro.core.matching import AdaptiveMatcher, MatcherState, matcher_scores
 from repro.data.pipeline import client_batch_indices, gather_client_batches
 from repro.fl.client import local_sgd
-from repro.fl.round import _FAULT_TAG, dispatch_aggregate
+from repro.fl.round import _FAULT_TAG, dispatch_aggregate, mean_local_loss
 from repro.utils.tree import tree_flatten_concat, tree_unflatten_concat
 
 # fold targets for the sparse-only PRNG streams: the round key's
@@ -438,12 +438,8 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
             env_state=env_state,
             fault_state=fault_state,
         )
-        loss_ok = jnp.isfinite(local_losses).astype(jnp.float32)
-        loss_w = active * loss_ok
         metrics = {
-            "local_loss": jnp.sum(
-                jnp.where(loss_ok > 0.5, local_losses, 0.0) * active)
-            / jnp.maximum(jnp.sum(loss_w), 1.0),
+            "local_loss": mean_local_loss(local_losses, active),
             "n_success": n_succ,
             "mean_aoi": jnp.mean(aoi),
             "aoi_var": aoi_variance(aoi),
@@ -688,12 +684,8 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
             env_state=env_state,
             fault_state=pre.fault_state,
         )
-        loss_ok = jnp.isfinite(pre.local_losses).astype(jnp.float32)
-        loss_w = active * loss_ok
         metrics = {
-            "local_loss": jnp.sum(
-                jnp.where(loss_ok > 0.5, pre.local_losses, 0.0) * active)
-            / jnp.maximum(jnp.sum(loss_w), 1.0),
+            "local_loss": mean_local_loss(pre.local_losses, active),
             "n_success": n_succ,
             "mean_aoi": jnp.mean(aoi),
             "aoi_var": aoi_variance(aoi),

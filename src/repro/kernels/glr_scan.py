@@ -12,8 +12,9 @@ points are evaluated in one vectorized pass.
 TPU mapping: channels ride the sublane dimension (blocks of 8), the
 stream rides the lane dimension (H padded to a multiple of 128).  Each
 grid step loads one (8, H) tile into VMEM, computes the running prefix
-sum with `jnp.cumsum` (lowered to an in-register scan), evaluates the KL
-terms for every split point on the VPU and writes one (8, 1) result tile.
+sum with a log-step shifted-add scan (``_prefix_sum``: ceil(log2 H) lane
+rotations, since Mosaic has no cumsum lowering), evaluates the KL terms for
+every split point on the VPU and writes one (8, 1) result tile.
 The working set per step is 8*H*4 bytes — H up to ~128k fits VMEM.
 """
 from __future__ import annotations
@@ -23,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _EPS = 1e-6  # float32-safe: 1.0 - 1e-9 rounds to 1.0 and poisons KL with 0*log(0)
 CHANNEL_BLOCK = 8
@@ -34,6 +36,20 @@ def _kl(p, q):
     return p * jnp.log(p / q) + (1.0 - p) * jnp.log((1.0 - p) / (1.0 - q))
 
 
+def _prefix_sum(x, idx):
+    """Inclusive prefix sum along lanes: Hillis-Steele log-step scan.
+
+    Each step adds the tile rotated right by d lanes, with the d wrapped-in
+    lanes masked to zero.  Every partial sum is a sum of the same samples
+    as a sequential cumsum, so {0, 1} streams (small exact integers) give
+    the oracle's prefixes bitwise; general floats agree to rounding."""
+    d = 1
+    while d < x.shape[-1]:
+        x = x + jnp.where(idx >= d, pltpu.roll(x, d, 1), 0.0)
+        d *= 2
+    return x
+
+
 def _glr_kernel(hist_ref, counts_ref, out_ref):
     hist = hist_ref[...].astype(jnp.float32)          # (Cb, H)
     n = counts_ref[...].astype(jnp.int32)             # (Cb, 1)
@@ -41,7 +57,7 @@ def _glr_kernel(hist_ref, counts_ref, out_ref):
 
     idx = jax.lax.broadcasted_iota(jnp.int32, (1, h), 1)
     masked = jnp.where(idx < n, hist, 0.0)
-    prefix = jnp.cumsum(masked, axis=-1)
+    prefix = _prefix_sum(masked, idx)
     total = jnp.sum(masked, axis=-1, keepdims=True)
 
     s = (idx + 1).astype(jnp.float32)

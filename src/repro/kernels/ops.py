@@ -1,9 +1,12 @@
 """Public jit'd entry points for the Pallas kernels.
 
-On TPU the compiled kernels run natively; on CPU (this container, and any
-unit-test environment) they execute under ``interpret=True``, which runs
-the kernel body in Python with identical semantics.  Models and the FL
-runtime call these wrappers, never the kernels directly.
+Every entry point takes a ``backend``: ``None`` (auto) runs the compiled
+Pallas kernel on TPU and the pure-jnp oracle (``repro.kernels.ref``)
+elsewhere; ``"pallas"`` demands the compiled kernel and raises off-TPU;
+``"pallas_interpret"`` runs the kernel body under Pallas' interpreter (the
+kernel-semantics tests on CPU); ``"jnp"`` runs the oracle.  Interpret mode
+happens only when it is asked for.  Models and the FL runtime call these
+wrappers, never the kernels directly.
 """
 from __future__ import annotations
 
@@ -20,11 +23,27 @@ from repro.kernels import weighted_aggregate as _wa
 from repro.kernels import ref as ref  # re-export the oracles
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 _GLR_BACKENDS = ("pallas", "pallas_interpret", "jnp")
+
+
+def _auto(backend: str | None) -> str:
+    if backend is None:
+        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+    return backend
+
+
+def _interpret(backend: str, name: str) -> bool:
+    """The ``interpret`` flag of a Pallas backend.  ``"pallas"`` is the
+    compiled kernel, which only a TPU can run: asking for it elsewhere is an
+    error, never a silent switch to the interpreter."""
+    if backend == "pallas_interpret":
+        return True
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"{name}: backend='pallas' needs a TPU, but JAX is running on "
+            f"{jax.default_backend()!r}; use 'pallas_interpret' for the "
+            f"interpreter or 'jnp' for the oracle")
+    return False
 
 
 def glr_scan(
@@ -39,22 +58,20 @@ def glr_scan(
 
       None               auto: "pallas" on TPU, "jnp" elsewhere (the hot-path
                          default used by ``GLRCUCB.update``)
-      "pallas"           compiled Pallas kernel (interpret mode off-TPU)
-      "pallas_interpret" Pallas kernel forced into interpret mode (kernel
-                         semantics tests)
+      "pallas"           compiled Pallas kernel (raises off-TPU)
+      "pallas_interpret" Pallas kernel in interpret mode (kernel semantics
+                         tests)
       "jnp"              the pure-jnp oracle in ``repro.kernels.ref``
 
     All backends implement identical semantics; tests assert the pallas and
     jnp paths agree inside a jitted ``GLRCUCB.update``.
     """
-    if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = _auto(backend)
     if backend == "jnp":
         return ref.glr_scan(hist, counts)
-    if backend == "pallas":
-        return _glr.glr_scan(hist, counts, interpret=_interpret())
-    if backend == "pallas_interpret":
-        return _glr.glr_scan(hist, counts, interpret=True)
+    if backend in ("pallas", "pallas_interpret"):
+        return _glr.glr_scan(hist, counts,
+                             interpret=_interpret(backend, "glr_scan"))
     raise ValueError(f"glr_scan: unknown backend {backend!r}; use one of {_GLR_BACKENDS}")
 
 
@@ -81,9 +98,9 @@ def glr_step(cum, total, base, counts, r_vec, sched,
 
       None               auto: "pallas" on TPU, "jnp" elsewhere (the hot-path
                          default used by ``GLRCUCB.update``)
-      "pallas"           compiled fused Pallas kernel (interpret mode off-TPU)
-      "pallas_interpret" Pallas kernel forced into interpret mode (kernel
-                         semantics tests)
+      "pallas"           compiled fused Pallas kernel (raises off-TPU)
+      "pallas_interpret" Pallas kernel in interpret mode (kernel semantics
+                         tests)
       "jnp"              the pure-jnp oracle in ``repro.kernels.ref`` (the
                          geometric grid gathers its O(log H) splits there;
                          the Pallas kernel masks the same set densely — the
@@ -100,8 +117,7 @@ def glr_step(cum, total, base, counts, r_vec, sched,
         raise ValueError(
             f"glr_step: unknown split_grid {split_grid!r}; "
             f"use one of {_GLR_SPLIT_GRIDS}")
-    if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = _auto(backend)
     tenants = jnp.ndim(cum) == 3
     if backend == "jnp":
         if tenants:
@@ -111,7 +127,7 @@ def glr_step(cum, total, base, counts, r_vec, sched,
         return ref.glr_step(cum, total, base, counts, r_vec, sched,
                             split_grid=split_grid)
     if backend in ("pallas", "pallas_interpret"):
-        interpret = True if backend == "pallas_interpret" else _interpret()
+        interpret = _interpret(backend, "glr_step")
         if tenants:
             return _gs.glr_step_tenants(cum, total, base, counts, r_vec,
                                         sched, split_grid=split_grid,
@@ -139,18 +155,17 @@ def weighted_aggregate(
     than the serial jnp path at batch 8).  Backends:
 
       None               auto: "pallas" on TPU, "jnp" elsewhere
-      "pallas"           compiled Pallas kernel (interpret mode off-TPU)
-      "pallas_interpret" Pallas kernel forced into interpret mode (tests)
+      "pallas"           compiled Pallas kernel (raises off-TPU)
+      "pallas_interpret" Pallas kernel in interpret mode (tests)
       "jnp"              the pure-jnp oracle in ``repro.kernels.ref``
     """
-    if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = _auto(backend)
     if backend == "jnp":
         return ref.weighted_aggregate(updates, scale)
-    if backend == "pallas":
-        return _wa.weighted_aggregate(updates, scale, interpret=_interpret())
-    if backend == "pallas_interpret":
-        return _wa.weighted_aggregate(updates, scale, interpret=True)
+    if backend in ("pallas", "pallas_interpret"):
+        return _wa.weighted_aggregate(
+            updates, scale,
+            interpret=_interpret(backend, "weighted_aggregate"))
     raise ValueError(
         f"weighted_aggregate: unknown backend {backend!r}; use one of {_WA_BACKENDS}")
 
@@ -176,20 +191,17 @@ def robust_trimmed(
     auto-selected on the hot path):
 
       None               auto: "pallas" on TPU, "jnp" elsewhere
-      "pallas"           compiled Pallas kernel (interpret mode off-TPU)
-      "pallas_interpret" Pallas kernel forced into interpret mode (tests)
+      "pallas"           compiled Pallas kernel (raises off-TPU)
+      "pallas_interpret" Pallas kernel in interpret mode (tests)
       "jnp"              the pure-jnp oracle in ``repro.kernels.ref``
     """
-    if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = _auto(backend)
     if backend == "jnp":
         return ref.robust_trimmed(updates, mask, n_succ, k_trim)
-    if backend == "pallas":
-        return _ra.robust_trimmed(updates, mask, n_succ, k_trim,
-                                  interpret=_interpret())
-    if backend == "pallas_interpret":
-        return _ra.robust_trimmed(updates, mask, n_succ, k_trim,
-                                  interpret=True)
+    if backend in ("pallas", "pallas_interpret"):
+        return _ra.robust_trimmed(
+            updates, mask, n_succ, k_trim,
+            interpret=_interpret(backend, "robust_trimmed"))
     raise ValueError(
         f"robust_trimmed: unknown backend {backend!r}; use one of {_RT_BACKENDS}")
 
@@ -203,13 +215,20 @@ def flash_attention(
     scale: float | None = None,
     block_q: int | None = None,
     block_k: int | None = None,
+    backend: str = "pallas",
 ) -> jnp.ndarray:
     """Blockwise GQA attention.  q (B,Hq,S,D), k/v (B,Hkv,S,D) -> (B,Hq,S,D).
 
     Pads the head dim to a 128-lane multiple (zero-padded dims contribute
     nothing to q.k^T or the weighted value sum, so the result is exact) and
-    picks MXU-aligned default tile sizes.
+    picks MXU-aligned default tile sizes.  ``backend`` is ``"pallas"`` (the
+    compiled kernel, TPU only) or ``"pallas_interpret"``; the oracle is
+    ``ref.mha_attention``.
     """
+    if backend not in ("pallas", "pallas_interpret"):
+        raise ValueError(
+            f"flash_attention: unknown backend {backend!r}; use 'pallas' or "
+            "'pallas_interpret'")
     d = q.shape[-1]
     scale = float(scale) if scale is not None else float(1.0 / (d ** 0.5))
     d_pad = (-d) % 128
@@ -222,6 +241,7 @@ def flash_attention(
     out = _fa.flash_attention(
         q, k, v,
         causal=causal, window=window, scale=scale,
-        block_q=bq, block_k=bk, interpret=_interpret(),
+        block_q=bq, block_k=bk,
+        interpret=_interpret(backend, "flash_attention"),
     )
     return out[..., :d]

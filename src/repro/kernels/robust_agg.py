@@ -46,7 +46,11 @@ def _trim_kernel(updates_ref, mask_ref, nk_ref, out_ref):
     for j in range(m):                                  # unrolled: M is small
         vj = x[j:j + 1, :]                              # (1, Pb)
         beats = (vj < x) | ((vj == x) & (j < row))
-        rank = rank + jnp.where(part[j, 0], beats.astype(jnp.float32), 0.0)
+        # row j's participation is read as an f32 scalar and compared
+        # there: Mosaic extracts only 32-bit scalars from a vector, never
+        # an element of the bool mask
+        part_j = mask_ref[j, 0] > 0.5
+        rank = rank + jnp.where(part_j, beats.astype(jnp.float32), 0.0)
     keep = part & (rank >= k) & (rank < n - k)
     denom = jnp.maximum(n - 2.0 * k, 1.0)
     out_ref[...] = jnp.sum(

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.core.bandits import GLRCUCB
 from repro.sim import SchedServer, ServeRequest
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def poisson_episode(server, tenant_ids, states, keys, arrivals,
@@ -196,6 +197,7 @@ def main():
                     help="evict+readmit one tenant every this many steps "
                          "(0 = no churn)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     sched = GLRCUCB(args.channels, args.clients, history=args.history,
                     detector_stride=5, split_grid="auto")

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from repro.configs import get_config, get_smoke_config, list_archs
 from repro.launch.steps import make_serve_step
 from repro.models import build_model
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--window", type=int, default=0,
                     help=">0: sliding-window ring cache (long-context mode)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg, remat="none")

@@ -68,10 +68,6 @@ def serve_window(cfg: ModelConfig, shape_name: str) -> int:
 
 
 def _sds(shape, dtype, mesh: Mesh, pspec: P):
-    if not isinstance(pspec, P):
-        # jax 0.4.x: PartitionSpec is a tuple subclass, so `P(...) + (None,)`
-        # decays to a plain tuple, which NamedSharding there rejects
-        pspec = P(*pspec)
     return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, pspec))
 
 

@@ -27,6 +27,7 @@ from repro.data.synthetic import synthetic_lm_batches
 from repro.launch.steps import make_fl_train_step, make_train_state_init
 from repro.models.model import Model
 from repro.optim import adamw
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def make_batch(cfg, batch, seq, key, data_iter=None):
@@ -60,6 +61,7 @@ def main():
     ap.add_argument("--ce-chunk", type=int, default=0)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg=cfg, remat="none" if args.smoke else "full",

@@ -33,22 +33,9 @@ def set_layout(layout: str) -> None:
     _SEQ_AXIS = _LAYOUT_SEQ_AXIS[layout]
 
 
-def _get_abstract_mesh():
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:  # jax < 0.5 exposes only the internal accessor
-        try:
-            from jax._src.mesh import get_abstract_mesh as get
-        except ImportError:
-            return None
-    mesh = get()
-    # jax 0.4.x returns the raw context stack (a tuple) instead of an
-    # AbstractMesh; fall through to the physical-mesh path in that case
-    return mesh if hasattr(mesh, "empty") else None
-
-
 def _current_mesh():
-    mesh = _get_abstract_mesh()
-    if mesh is not None and not mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty:
         return mesh
     try:  # `with mesh:` (physical Mesh context) doesn't set the abstract mesh
         from jax._src.mesh import thread_resources

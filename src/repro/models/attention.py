@@ -136,10 +136,16 @@ def attn_core(
         def _xla(qq, kk, vv):
             return _attn_core_xla(qq, kk, vv, causal, window, scale, chunk)
 
+        # REPRO_ATTN_IMPL=flash off-TPU explicitly asks for the kernel,
+        # which only the interpreter can run there
+        backend = ("pallas" if jax.default_backend() == "tpu"
+                   else "pallas_interpret")
+
         @jax.custom_vjp
         def _flash(qq, kk, vv):
             return _kernel_ops.flash_attention(
-                qq, kk, vv, causal=causal, window=window, scale=scale)
+                qq, kk, vv, causal=causal, window=window, scale=scale,
+                backend=backend)
 
         def _fwd(qq, kk, vv):
             return _flash(qq, kk, vv), (qq, kk, vv)

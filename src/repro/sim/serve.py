@@ -30,10 +30,10 @@ Sharded capacity
 ``SchedServer(..., shard=True)`` places every ``TenantSlots`` leaf over the
 1-D "cases" device mesh (``repro.sim.shard.shard_slots`` — the same
 ``NamedSharding`` recipe the sparse FL client axis rides).  The serve step
-is gather / per-row compute / scatter on slot indices, so the tenant axis
-partitions exactly like the sparse client axis: XLA splits the O(capacity)
-state residency and the per-row math across devices with no cross-device
-traffic beyond the (slots,) gathers.  On a single device the placement is
+is gather / per-row compute / scatter on slot indices, so XLA splits the
+O(capacity) state residency across devices with no cross-device traffic
+beyond the (slots,) gathers; the per-row math runs replicated inside
+``shard_map`` (see ``make_serve_step``).  On a single device the placement is
 the identity — results are bitwise unchanged — which is what lets
 ``capacity`` grow to 10^4–10^5 tenants without touching the step program.
 Host bookkeeping stays O(1) per join/leave at any capacity: the free-slot
@@ -128,6 +128,7 @@ from typing import (
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint.io import restore_checkpoint, save_checkpoint
 
@@ -249,7 +250,8 @@ def init_slots(scheduler, capacity: int, matcher_beta: float = 0.5,
 
 
 def make_serve_step(scheduler, use_matching: bool = False,
-                    matcher_beta: float = 0.5, score_kind: str = "ucb"):
+                    matcher_beta: float = 0.5, score_kind: str = "ucb",
+                    mesh=None):
     """Build the batched serving step ``(state, slots, rewards, keys,
     contrib, aoi, aoi_set, mask) -> (state, assignment, matcher_state)``.
 
@@ -267,6 +269,13 @@ def make_serve_step(scheduler, use_matching: bool = False,
     exactly like ``repro.core.matching.matcher_scores``: ``"ucb"`` uses the
     policy's native ``channel_scores`` (Eq. 30), ``"mean"`` its historical
     ``mean_scores`` (Eq. 31) when the policy provides them.
+
+    ``mesh`` (the sharded server's) runs the per-request math under
+    ``shard_map``, replicated on every device: the compiler cannot partition
+    a Pallas kernel (the detector's ``glr_step``), and the B gathered rows
+    are tiny next to the sharded slot state, whose gather and scatter stay
+    partitioned.  Every device computes the unsharded program, so results
+    are bitwise those of the unsharded step.
     """
     matcher = AdaptiveMatcher(matcher_beta)
 
@@ -308,12 +317,17 @@ def make_serve_step(scheduler, use_matching: bool = False,
         )
         return new_row, assignment
 
+    rows_fn = jax.vmap(one)
+    if mesh is not None:
+        rows_fn = jax.shard_map(rows_fn, mesh=mesh, in_specs=P(),
+                                out_specs=P(), check_vma=False)
+
     def serve_step(state: TenantSlots, slots, rewards, keys, contrib,
                    aoi, aoi_set, mask):
         sub = jax.tree_util.tree_map(lambda x: x[slots], state)
         live = mask & sub.active
-        new_rows, assignment = jax.vmap(one)(sub, rewards, keys, contrib,
-                                             aoi, aoi_set)
+        new_rows, assignment = rows_fn(sub, rewards, keys, contrib, aoi,
+                                       aoi_set)
 
         def merge(new, old):
             m = live.reshape(live.shape + (1,) * (new.ndim - 1))
@@ -436,6 +450,14 @@ class SchedServer:
                                  rows=self.rows)
         if self.shard:
             self._state = shard_slots(self._state, self._mesh)
+        # pin the slot state's output placement to its input placement on a
+        # mesh: left to the compiler, a zero-size leaf (the streaming
+        # detector's (rows, N, 0) ``hist``) comes back replicated, and the
+        # next call's AOT executable — lowered for the sharded input — then
+        # refuses it
+        self._state_out = (jax.tree_util.tree_map(lambda x: x.sharding,
+                                                  self._state)
+                           if self.shard else None)
         self._tenants: Dict[Any, int] = {}
         self._free = _FreePool(capacity)
         self._hp_defaults = dict(getattr(scheduler, "params", dict)())
@@ -450,7 +472,8 @@ class SchedServer:
         self._backend = jax.default_backend()
         self._step_fn = make_serve_step(scheduler, use_matching=use_matching,
                                         matcher_beta=matcher_beta,
-                                        score_kind=score_kind)
+                                        score_kind=score_kind,
+                                        mesh=self._mesh)
         # batch-size ladder for serve_stream autosizing: powers of two up
         # to `slots` (plus `slots` itself) — each size is its own AOT-cached
         # executable, so resizing between them never recompiles after warmup
@@ -473,7 +496,8 @@ class SchedServer:
             ("serve_admit", self._sig, capacity, self.rows,
              float(matcher_beta), tuple(sorted(self._hp_defaults)),
              self._donate, self._backend, self._mesh),
-            lambda: jax.jit(admit_fn, donate_argnums=donate_idx).lower(*admit_ex))
+            lambda: jax.jit(admit_fn, donate_argnums=donate_idx,
+                            out_shardings=self._state_out).lower(*admit_ex))
         self.compile_s += admit_compile_s
         self.compiles += int(not admit_hit)
 
@@ -497,8 +521,9 @@ class SchedServer:
             ("serve_step", self._sig, self.capacity, self.rows, b,
              self.use_matching, float(self.matcher_beta), self.score_kind,
              self._donate, self._backend, self._mesh),
-            lambda: jax.jit(self._step_fn,
-                            donate_argnums=donate_idx).lower(*step_ex))
+            lambda: jax.jit(self._step_fn, donate_argnums=donate_idx,
+                            out_shardings=(self._state_out, None, None)
+                            ).lower(*step_ex))
         self._step_cache[b] = fn
         self.compile_s += compile_s
         self.compiles += int(not hit)

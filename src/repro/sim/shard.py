@@ -3,7 +3,7 @@
 Sweep buckets are embarrassingly parallel: every batch entry of a
 ``simulate_aoi_regret_batch`` call is an independent (env, key, hp)
 simulation.  This module splits the batch axis across a 1-D device mesh
-with ``jax.experimental.shard_map`` — each device runs the same vmapped
+with ``jax.shard_map`` — each device runs the same vmapped
 scan over its slice of the bucket, with no cross-device communication at
 all — so multi-chip hosts sweep D buckets' worth of Monte-Carlo cases in
 the wall-clock of one.
@@ -33,7 +33,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.regret import simulate_aoi_regret_impl
@@ -101,10 +100,10 @@ def shard_slots(tree, mesh: Optional[Mesh] = None):
     The serving tier's ``TenantSlots`` leaves all lead with the slot axis
     (``rows``, mesh-divisible — ``SchedServer`` pads with extra scratch
     rows), and the serve step is gather / per-row compute / scatter on slot
-    indices, so the tenant axis partitions exactly like the sparse FL
-    client axis above: a ``NamedSharding`` over the same 1-D "cases" mesh
-    splits the O(capacity) state residency and per-row math across devices
-    with no cross-device traffic beyond the (slots,) gathers.  On a single
+    indices, so a ``NamedSharding`` over the same 1-D "cases" mesh splits
+    the O(capacity) state residency across devices with no cross-device
+    traffic beyond the (slots,) gathers (the per-row math itself runs
+    replicated inside ``shard_map``: see ``make_serve_step``).  On a single
     device this is the identity placement — serving results are bitwise
     unchanged (asserted in ``tests/test_serve_scale.py``, which CI also
     runs under a forced 4-device CPU mesh).
@@ -162,12 +161,12 @@ def build_sharded(
             envs, keys, hparams)
 
     spec = lambda axis: P(_AXIS) if axis == 0 else P()
-    fn = shard_map(
+    fn = jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(spec(env_axis), spec(key_axis), spec(hp_axis)),
         out_specs=P(_AXIS),
-        check_rep=False,
+        check_vma=False,
     )
     _FN_CACHE[cache_key] = fn
     return fn
@@ -195,12 +194,12 @@ def build_fl_sharded(trainer, mesh: Mesh):
         return trainer._run_vmapped(states, bx, by, keys, envs=envs,
                                     env_axis=0)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(P(_AXIS), P(_AXIS), P(_AXIS), P(_AXIS), P(_AXIS)),
         out_specs=P(_AXIS),
-        check_rep=False,
+        check_vma=False,
     )
     _FN_CACHE[cache_key] = fn
     return fn

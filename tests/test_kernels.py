@@ -64,6 +64,32 @@ def test_glr_scan_dispatch_rejects_unknown_backend():
         ops.glr_scan(hist, jnp.array([4, 4]), backend="cuda")
 
 
+_PALLAS_CALLS = {
+    "glr_scan": lambda: ops.glr_scan(jnp.zeros((2, 32)), jnp.array([4, 4]),
+                                     backend="pallas"),
+    "glr_step": lambda: ops.glr_step(
+        jnp.zeros((2, 32)), *(jnp.zeros((2,)),) * 4, jnp.ones((2,), bool),
+        backend="pallas"),
+    "weighted_aggregate": lambda: ops.weighted_aggregate(
+        jnp.zeros((3, 8)), jnp.ones((3,)), backend="pallas"),
+    "robust_trimmed": lambda: ops.robust_trimmed(
+        jnp.zeros((3, 8)), jnp.ones((3,)), jnp.asarray(3.0),
+        jnp.asarray(1.0), backend="pallas"),
+    "flash_attention": lambda: ops.flash_attention(
+        *(jnp.zeros((1, 1, 8, 8)),) * 3, backend="pallas"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PALLAS_CALLS))
+def test_explicit_pallas_backend_raises_off_tpu(name):
+    """``backend="pallas"`` asks for the compiled kernel: off-TPU that is an
+    error, never a silent switch to interpret mode."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the compiled kernel runs here")
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        _PALLAS_CALLS[name]()
+
+
 def _drive_glr_cucb(sched, t_rounds, n, m):
     """Run a jitted select/update loop long enough to wrap the ring buffer."""
 
@@ -163,7 +189,8 @@ def test_flash_attention_matches_oracle(b, hq, hkv, s, d, causal, window):
     q = jax.random.normal(k1, (b, hq, s, d), jnp.float32) * 0.5
     k = jax.random.normal(k2, (b, hkv, s, d), jnp.float32) * 0.5
     v = jax.random.normal(k3, (b, hkv, s, d), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=causal, window=window)
+    got = ops.flash_attention(q, k, v, causal=causal, window=window,
+                              backend="pallas_interpret")
     want = ref.mha_attention(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
@@ -172,7 +199,7 @@ def test_flash_attention_bf16():
     q = jax.random.normal(KEY, (1, 2, 128, 64), jnp.bfloat16)
     k = jax.random.normal(jax.random.fold_in(KEY, 1), (1, 2, 128, 64), jnp.bfloat16)
     v = jax.random.normal(jax.random.fold_in(KEY, 2), (1, 2, 128, 64), jnp.bfloat16)
-    got = ops.flash_attention(q, k, v)
+    got = ops.flash_attention(q, k, v, backend="pallas_interpret")
     want = ref.mha_attention(q, k, v)
     np.testing.assert_allclose(
         got.astype(jnp.float32), want.astype(jnp.float32), rtol=3e-2, atol=3e-2)
@@ -184,6 +211,6 @@ def test_flash_attention_matches_model_attn_core():
     q = jax.random.normal(KEY, (1, 4, 300, 64)) * 0.3
     k = jax.random.normal(jax.random.fold_in(KEY, 1), (1, 2, 300, 64)) * 0.3
     v = jax.random.normal(jax.random.fold_in(KEY, 2), (1, 2, 300, 64))
-    a = ops.flash_attention(q, k, v, causal=True)
+    a = ops.flash_attention(q, k, v, causal=True, backend="pallas_interpret")
     b = attn_core(q, k, v, causal=True, chunk=128)
     np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-3)

@@ -73,10 +73,7 @@ def test_input_specs_cover_all_arch_shape_pairs():
     from jax.sharding import AbstractMesh
     from repro.configs import get_config
     from repro.launch.specs import SHAPES, batch_specs, cache_specs, supported
-    try:
-        mesh = AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:   # jax 0.4.x: AbstractMesh(((name, size), ...))
-        mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     n_ok = n_skip = 0
     for arch in list_archs():
         cfg = get_config(arch)
