@@ -6,8 +6,10 @@ jobs, measures pipelined-vs-synchronous saturated throughput at equal
 batch size, then replays Poisson request traffic through the pipelined
 ``serve_stream`` loop (autosized slot batches, churn interleaved with
 in-flight steps) and reports p50/p99/p999 decision latency, queue depth,
-batch occupancy and decisions/sec.  The synchronous ``poisson_episode``
-baseline is kept alongside for comparison runs.
+batch occupancy and decisions/sec, and the service's own split of that
+latency (``stats()``: queue wait and in flight, p50/p99), which it counts
+while a profiler session is on (for example the launcher run under
+``jax.profiler.trace``).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.sched_serve --tenants 256 --slots 64
@@ -17,7 +19,6 @@ from __future__ import annotations
 
 import argparse
 import time
-from collections import deque
 
 import jax
 import jax.numpy as jnp
@@ -25,62 +26,8 @@ import numpy as np
 
 from repro.core.bandits import GLRCUCB
 from repro.sim import SchedServer, ServeRequest
+from repro.sim.serve import latency_quantile
 from repro.utils.compile_cache import enable_compile_cache
-
-
-def poisson_episode(server, tenant_ids, states, keys, arrivals,
-                    churn_stride: int = 0, churn_hp=None):
-    """Replay Poisson request traffic through ``server``; returns
-    ``(latencies_s, wall_s, churn_events)``.
-
-    Request j targets ``tenant_ids[j % len(tenant_ids)]`` with reward
-    vector ``states[(j // len(tenant_ids)) % states.shape[0], j % len(...)]``
-    and round key ``keys[j]``; it becomes eligible at ``arrivals[j]``
-    seconds after the clock starts.  Every ``churn_stride`` steps one
-    tenant is evicted and immediately re-admitted with fresh state (the
-    leave+join pair re-enters the server's cached admit executable — zero
-    compiles).  The throughput clock blocks on the final state update
-    (``jax.block_until_ready``) before it is read: un-retired async work
-    must not count as served.
-    """
-    n_req = len(arrivals)
-    n_ten = len(tenant_ids)
-    lat = np.empty(n_req)
-    queue: deque = deque()
-    nxt = 0
-    served = 0
-    steps = 0
-    churn_events = 0
-    churn_ptr = 0
-    t0 = time.perf_counter()
-    while served < n_req:
-        now = time.perf_counter() - t0
-        while nxt < n_req and arrivals[nxt] <= now:
-            queue.append(nxt)
-            nxt += 1
-        if not queue:
-            time.sleep(min(max(arrivals[nxt] - now, 0.0), 1e-3))
-            continue
-        ids = [queue.popleft()
-               for _ in range(min(server.slots, len(queue)))]
-        reqs = [ServeRequest(tenant_ids[j % n_ten],
-                             states[(j // n_ten) % states.shape[0], j % n_ten],
-                             keys[j]) for j in ids]
-        server.serve(reqs)
-        done = time.perf_counter() - t0
-        for j in ids:
-            lat[j] = done - arrivals[j]
-        served += len(ids)
-        steps += 1
-        if churn_stride and steps % churn_stride == 0:
-            tid = tenant_ids[churn_ptr % n_ten]
-            churn_ptr += 1
-            server.leave(tid)
-            server.join(tid, hp=churn_hp)
-            churn_events += 1
-    jax.block_until_ready(server._state)   # retire the last async state update
-    wall = time.perf_counter() - t0
-    return lat, wall, churn_events
 
 
 def saturated_throughput(server, tenant_ids, states, keys, n_req: int):
@@ -236,6 +183,7 @@ def main():
     rng = np.random.default_rng(0)
     arrivals = np.cumsum(rng.exponential(1.0 / lam, size=n_req))
 
+    st0 = server.stats()
     lat, wall, churn, depths = pipelined_poisson_episode(
         server, tenant_ids, states, keys, arrivals,
         churn_stride=args.churn_stride)
@@ -248,6 +196,18 @@ def main():
           f"mean={depths.mean():.1f} max={depths.max()}, churn_events={churn}, "
           f"batch_occupancy={st['batch_occupancy']:.2f}, sizes_used="
           f"{st['sizes_used']}, compiles={st['compiles']}")
+    # the episode's share of the service's cumulative histograms, which
+    # count only while a profiler session is on
+    split = []
+    for name, key in (("queue wait", "queue_wait_counts"),
+                      ("in flight", "inflight_counts")):
+        counts = np.subtract(st[key], st0[key])
+        p50, p99 = (latency_quantile(st["latency_edges_s"], counts, q)
+                    for q in (50, 99))
+        split.append(f"{name} p50={p50 * 1e3:.2f}ms p99={p99 * 1e3:.2f}ms"
+                     if p50 is not None else f"{name} not counted")
+    print("[sched-serve] the service's split of that latency: "
+          + ", ".join(split) + " (counted while a profiler session is on)")
 
 
 if __name__ == "__main__":

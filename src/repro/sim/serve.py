@@ -72,6 +72,27 @@ recompiles after warmup.  ``tests/test_serve_scale.py`` pins the stream's
 output bitwise-equal to the synchronous loop over the same request trace,
 including across churn and mid-stream resizes.
 
+Spans and latency counters
+--------------------------
+Each ``serve_stream`` iteration opens one ``jax.profiler.TraceAnnotation``
+per phase it works in, with the stream step and batch size as metadata
+(``step``, ``b``) while a profiler session is on: ``sched.source`` (pulling
+from the caller's iterable; the caller's own work nests inside),
+``sched.take_batch``, ``sched.pack`` (with ``_sanitize_rewards``),
+``sched.dispatch`` (``_get_step`` and the executable call),
+``sched.fetch`` (the previous step's assignment to host memory) and
+``sched.deliver`` (yielding its answers; the caller's handling nests
+inside).  ``join``/``leave`` open ``sched.admit``, and an executable-cache
+miss ``sched.compile``.  While a session is on, two cumulative histograms
+over ``LATENCY_EDGES_S`` time the streamed requests, one clock read each:
+*queue wait*, from when the stream pulled a request from its source to when
+its step's executable call returned, and *in flight*, from then to the end
+of ``sched.fetch``.  A step only logs its times; they are binned,
+vectorised, once ``_LATENCY_BIN_AFTER`` requests are logged and whenever
+``stats()`` reports them.  With no session on, an iteration costs one
+``TraceAnnotation.is_enabled()`` and a no-op context manager per phase: a
+clock read per request was measurable on a host whose clock is slow to read.
+
 Boundary hygiene and crash recovery
 -----------------------------------
 Reward vectors are sanitized at the packing boundary (``_sanitize_rewards``):
@@ -116,9 +137,12 @@ reproduces its standalone ``run()`` bitwise (``tests/test_fl_served.py``).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import itertools
 import json
 import os
+import time
 from collections import deque
 from typing import (
     Any, Dict, Iterable, Iterator, List, NamedTuple, Optional, Sequence,
@@ -128,6 +152,7 @@ from typing import (
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint.io import restore_checkpoint, save_checkpoint
@@ -138,6 +163,50 @@ from repro.core.matching import AdaptiveMatcher, MatcherState
 from repro.core.regret import policy_round
 from repro.sim.shard import shard_slots, sweep_mesh
 from repro.sim.sweep import _sched_sig, cached_compile
+
+# Bucket edges of serve_stream's latency histograms, in seconds: geometric,
+# 25 a decade (ratio 10**0.04, about 1.096) from 1 us to 100 s.  Bucket 0
+# counts times under 1 us, bucket i times in [edges[i-1], edges[i]), and the
+# last bucket 100 s and more.
+_PER_DECADE = 25
+LATENCY_EDGES_S = np.geomspace(1e-6, 100.0, 8 * _PER_DECADE + 1)
+# requests whose logged times serve_stream folds into the histograms at once
+_LATENCY_BIN_AFTER = 4096
+# a phase's span while no profiler session is on
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _bucket_counts(seconds, weights=None) -> np.ndarray:
+    """Counts of ``seconds`` in the buckets of ``LATENCY_EDGES_S``, each
+    time counted ``weights`` times where given.  The bucket comes from the
+    logarithm (a tenth of the cost of a search over the edges)."""
+    with np.errstate(divide="ignore"):
+        pos = np.floor(np.log10(np.asarray(seconds, np.float64) * 1e6)
+                       * _PER_DECADE)
+    idx = np.clip(pos, -1, LATENCY_EDGES_S.size - 1).astype(np.int64) + 1
+    return np.bincount(idx, weights, minlength=LATENCY_EDGES_S.size + 1
+                       ).astype(np.int64)
+
+
+def latency_quantile(edges, counts, q: float) -> Optional[float]:
+    """The ``q``-th percentile, in seconds, of a latency histogram as
+    ``stats()`` reports it (``latency_edges_s`` and one of the ``*_counts``),
+    interpolated linearly inside its bucket; ``None`` for an empty one.  The
+    first bucket spans [0, edges[0]); the open last bucket gives its edge."""
+    counts = np.asarray(counts, np.float64)
+    total = counts.sum()
+    if total <= 0:
+        return None
+    lo_edges = np.concatenate([[0.0], edges])
+    rank = q / 100.0 * total
+    cum = np.cumsum(counts)
+    # the first bucket whose count reaches the rank, and a non-empty one
+    i = max(int(np.searchsorted(cum, rank, side="left")),
+            int(np.argmax(counts > 0)))
+    if i >= len(edges):
+        return float(edges[-1])
+    lo, hi = lo_edges[i], edges[i]
+    return float(lo + (hi - lo) * (rank - (cum[i] - counts[i])) / counts[i])
 
 
 class TenantSlots(NamedTuple):
@@ -467,6 +536,14 @@ class SchedServer:
         self._rows_dispatched = 0
         self._sizes_used: Dict[int, int] = {}
         self._bad_rewards: Dict[Any, int] = {}
+        self._queue_wait = np.zeros(LATENCY_EDGES_S.size + 1, np.int64)
+        self._inflight = np.zeros(LATENCY_EDGES_S.size + 1, np.int64)
+        # logged by serve_stream, not binned yet: per dispatched step (call
+        # returned at, its requests' pull times), per fetched step (in-flight
+        # seconds, requests), and how many requests the first holds
+        self._wait_log: List[Tuple[float, List[float]]] = []
+        self._flight_log: List[Tuple[float, int]] = []
+        self._logged = 0
 
         self._sig = _sched_sig(scheduler)
         self._backend = jax.default_backend()
@@ -497,7 +574,8 @@ class SchedServer:
              float(matcher_beta), tuple(sorted(self._hp_defaults)),
              self._donate, self._backend, self._mesh),
             lambda: jax.jit(admit_fn, donate_argnums=donate_idx,
-                            out_shardings=self._state_out).lower(*admit_ex))
+                            out_shardings=self._state_out).lower(*admit_ex),
+            span=lambda: TraceAnnotation("sched.compile"))
         self.compile_s += admit_compile_s
         self.compiles += int(not admit_hit)
 
@@ -523,7 +601,8 @@ class SchedServer:
              self._donate, self._backend, self._mesh),
             lambda: jax.jit(self._step_fn, donate_argnums=donate_idx,
                             out_shardings=(self._state_out, None, None)
-                            ).lower(*step_ex))
+                            ).lower(*step_ex),
+            span=lambda: TraceAnnotation("sched.compile", b=b))
         self._step_cache[b] = fn
         self.compile_s += compile_s
         self.compiles += int(not hit)
@@ -563,15 +642,16 @@ class SchedServer:
             raise ValueError(
                 f"SchedServer.join: unknown hyper-parameters {sorted(unknown)} "
                 f"(traced: {sorted(self._hp_defaults)})")
-        merged = {k: jnp.asarray(overrides.get(k, v), jnp.float32)
-                  for k, v in self._hp_defaults.items()}
-        if key is None:
-            key = jax.random.fold_in(
-                jax.random.PRNGKey(0), len(self._tenants) + 1)
-        slot = self._free.pop()
-        self._state = self._admit(
-            self._state, jnp.asarray(slot, jnp.int32),
-            jnp.asarray(key, jnp.uint32), merged, jnp.asarray(True))
+        with TraceAnnotation("sched.admit"):
+            merged = {k: jnp.asarray(overrides.get(k, v), jnp.float32)
+                      for k, v in self._hp_defaults.items()}
+            if key is None:
+                key = jax.random.fold_in(
+                    jax.random.PRNGKey(0), len(self._tenants) + 1)
+            slot = self._free.pop()
+            self._state = self._admit(
+                self._state, jnp.asarray(slot, jnp.int32),
+                jnp.asarray(key, jnp.uint32), merged, jnp.asarray(True))
         self._tenants[tenant] = slot
         return slot
 
@@ -581,12 +661,13 @@ class SchedServer:
         slot = self._tenants.pop(tenant, None)
         if slot is None:
             raise KeyError(f"SchedServer.leave: unknown tenant {tenant!r}")
-        self._state = self._admit(
-            self._state, jnp.asarray(slot, jnp.int32),
-            jnp.zeros((2,), jnp.uint32),
-            {k: jnp.asarray(v, jnp.float32)
-             for k, v in self._hp_defaults.items()},
-            jnp.asarray(False))
+        with TraceAnnotation("sched.admit"):
+            self._state = self._admit(
+                self._state, jnp.asarray(slot, jnp.int32),
+                jnp.zeros((2,), jnp.uint32),
+                {k: jnp.asarray(v, jnp.float32)
+                 for k, v in self._hp_defaults.items()},
+                jnp.asarray(False))
         self._free.push(slot)
 
     @property
@@ -866,25 +947,38 @@ class SchedServer:
         costs zero recompiles).
         """
         pending: deque = deque()
-        inflight: Optional[Tuple[List[int], Any]] = None
+        # (stream indices, assignment, step, batch size, call returned at
+        # while a profiler session is on)
+        inflight: Optional[Tuple[List[int], Any, int, int,
+                                 Optional[float]]] = None
         it = iter(requests)
         exhausted = False
         draining = False
         next_index = 0
+        clock = time.perf_counter
+        pulled: Dict[int, float] = {}     # stream index -> pull time
         while True:
+            # spans, their metadata and the latency counters only while a
+            # profiler session is on
+            on = TraceAnnotation.is_enabled()
             # ---- pull from the source until a full batch / flush / end ----
-            while not exhausted and not draining and len(pending) < self.slots:
-                try:
-                    rq = next(it)
-                except StopIteration:
-                    exhausted = True
-                    draining = True
-                    break
-                if rq is None:
-                    draining = True
-                    break
-                pending.append((next_index, rq))
-                next_index += 1
+            if not exhausted and not draining and len(pending) < self.slots:
+                with (TraceAnnotation("sched.source", step=self._stream_steps)
+                      if on else _NO_SPAN):
+                    while len(pending) < self.slots:
+                        try:
+                            rq = next(it)
+                        except StopIteration:
+                            exhausted = True
+                            draining = True
+                            break
+                        if rq is None:
+                            draining = True
+                            break
+                        if on:
+                            pulled[next_index] = clock()
+                        pending.append((next_index, rq))
+                        next_index += 1
 
             # ---- dispatch the next step (device work starts now) ----------
             dispatched = None
@@ -892,11 +986,28 @@ class SchedServer:
                 depth = len(pending)
                 b = self._pick_size(min(depth, self.slots)) if autosize \
                     else self.slots
-                batch = self._take_batch(pending, b)
-                args = self._pack(batch, b)
-                step = self._get_step(b)
-                self._state, assignment, _ = step(self._state, *args)
-                dispatched = ([i for (i, _, _) in batch], assignment)
+                k = self._stream_steps
+                with (TraceAnnotation("sched.take_batch", step=k, b=b)
+                      if on else _NO_SPAN):
+                    batch = self._take_batch(pending, b)
+                with (TraceAnnotation("sched.pack", step=k, b=b)
+                      if on else _NO_SPAN):
+                    args = self._pack(batch, b)
+                with (TraceAnnotation("sched.dispatch", step=k, b=b)
+                      if on else _NO_SPAN):
+                    step = self._get_step(b)
+                    self._state, assignment, _ = step(self._state, *args)
+                returned = clock() if on else None
+                idxs = [i for (i, _, _) in batch]
+                if pulled:
+                    # requests pulled while no session was on have no time
+                    taken = [pulled.pop(i) for i in idxs if i in pulled]
+                    if on and taken:
+                        self._wait_log.append((returned, taken))
+                        self._logged += len(taken)
+                        if self._logged >= _LATENCY_BIN_AFTER:
+                            self._bin_latencies()
+                dispatched = (idxs, assignment, k, b, returned)
                 self._served += len(batch)
                 self._steps += 1
                 self._stream_steps += 1
@@ -907,15 +1018,43 @@ class SchedServer:
 
             # ---- retire the PREVIOUS step while this one is in flight -----
             if inflight is not None:
-                idxs, asg = inflight
-                host = np.asarray(asg)
-                for j, i in enumerate(idxs):
-                    yield i, host[j]
+                idxs, asg, k, b, returned = inflight
+                with (TraceAnnotation("sched.fetch", step=k, b=b)
+                      if on else _NO_SPAN):
+                    host = np.asarray(asg)
+                if on and returned is not None:
+                    self._flight_log.append((clock() - returned, len(idxs)))
+                with (TraceAnnotation("sched.deliver", step=k, b=b)
+                      if on else _NO_SPAN):
+                    for j, i in enumerate(idxs):
+                        yield i, host[j]
             inflight = dispatched
             if inflight is None and not pending and exhausted:
                 return
 
+    def _bin_latencies(self) -> None:
+        """Fold the times ``serve_stream`` logged into the histograms."""
+        if self._wait_log:
+            per_step = [len(p) for _, p in self._wait_log]
+            returned = np.repeat([t for t, _ in self._wait_log], per_step)
+            pulled = np.fromiter(
+                itertools.chain.from_iterable(p for _, p in self._wait_log),
+                np.float64, returned.size)
+            self._queue_wait += _bucket_counts(returned - pulled)
+            self._wait_log.clear()
+            self._logged = 0
+        if self._flight_log:
+            seconds, requests = zip(*self._flight_log)
+            self._inflight += _bucket_counts(seconds, requests)
+            self._flight_log.clear()
+
     def stats(self) -> Dict[str, Any]:
+        """The service's counters, in containers of their own, so that a
+        snapshot stays as it was while the service goes on.
+        ``queue_wait_counts`` and ``inflight_counts`` are ``serve_stream``'s
+        latency histograms over the buckets of ``latency_edges_s`` (see
+        ``LATENCY_EDGES_S``; ``latency_quantile`` reads a percentile)."""
+        self._bin_latencies()
         rows = max(self._rows_dispatched, 1)
         return {"tenants": len(self._tenants), "capacity": self.capacity,
                 "rows": self.rows, "slots": self.slots,
@@ -926,4 +1065,7 @@ class SchedServer:
                 "sizes_used": dict(self._sizes_used),
                 "bad_rewards": dict(self._bad_rewards),
                 "sharded": self.shard,
-                "compiles": self.compiles, "compile_s": self.compile_s}
+                "compiles": self.compiles, "compile_s": self.compile_s,
+                "latency_edges_s": LATENCY_EDGES_S.tolist(),
+                "queue_wait_counts": self._queue_wait.tolist(),
+                "inflight_counts": self._inflight.tolist()}

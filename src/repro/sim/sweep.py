@@ -56,6 +56,7 @@ regret buckets (bitwise identical on one device).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -200,13 +201,15 @@ def clear_sweep_cache() -> None:
     _EXEC_STATS.update(hits=0, misses=0)
 
 
-def cached_compile(cache_key, do_lower):
+def cached_compile(cache_key, do_lower, span=None):
     """AOT-compile through the process-level executable cache.
 
     ``do_lower()`` must return a ``jax.stages.Lowered``; its ``.compile()``
     result is memoized under ``cache_key`` and returned as ``(compiled,
     compile_s, cache_hit)``.  A compiled executable must be invoked with
-    the exact arg/kwarg split it was lowered with.
+    the exact arg/kwarg split it was lowered with.  On a miss, the context
+    manager that ``span()`` returns (if given) is held over the lowering
+    and compile: the serving loop passes a ``sched.compile`` profiler span.
 
     Public so other drivers share ONE cache and ONE accounting stream with
     the sweep: the multi-tenant serving loop (``repro.sim.serve``) registers
@@ -220,7 +223,8 @@ def cached_compile(cache_key, do_lower):
         _EXEC_STATS["hits"] += 1
         return compiled, 0.0, True
     t0 = time.perf_counter()
-    compiled = do_lower().compile()
+    with span() if span is not None else contextlib.nullcontext():
+        compiled = do_lower().compile()
     compile_s = time.perf_counter() - t0
     _EXEC_CACHE[cache_key] = compiled
     _EXEC_STATS["misses"] += 1
