@@ -59,7 +59,11 @@ from repro.core.channels import (
     PiecewiseProcess, ShadowingProcess, make_scenario, make_stationary,
     random_piecewise_env, realize_processes, scenario_realize_key)
 from repro.core.regret import simulate_aoi_regret
-from repro.data.pipeline import client_batch_indices, gather_client_batches
+from repro.data.pipeline import (
+    client_batch_indices,
+    gather_backends,
+    gather_client_batches,
+)
 from repro.fl import (AsyncFLConfig, AsyncFLTrainer, SparseAsyncFLTrainer,
                       SparseFLConfig)
 from repro.fl.sparse import _DATA_TAG
@@ -405,7 +409,8 @@ def phase_trainer(n=100_000, m=64, nch=16, d=16, nex=8, bsz=4, rounds=4,
         report_kernel(
             f"sparse trainer scan ({agg})",
             type(tr)._run_plain.lower(tr, tr.init(params0, KEY), cx, cy,
-                                      keys, tr.env).compile(),
+                                      keys, tr.env,
+                                      gather_backends(cx, cy)).compile(),
             expect_kernel)
 
     # -- dense == sparse at M = N (the dense trainer's donated path) -------

@@ -29,6 +29,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import client_gather as gather_kernel
+from repro.kernels import ops
+
 
 def client_batch_indices(
     key: jax.Array,
@@ -53,25 +56,69 @@ def client_batch_indices(
     return jax.vmap(one)(client_ids)
 
 
+def gather_backend(data) -> str:
+    """The ``repro.kernels.ops.client_gather`` backend that reads ``data``
+    (an (N, n, d) dataset or (N, n) labels) without copying it.
+
+    ``"pallas"`` where ``data`` lies on one TPU, stored client-minor: its
+    layout's ``major_to_minor`` ends in the client axis 0 with the other
+    axes in order, so that the transposed view (n, d, N) the kernel reads
+    is the stored bytes.  A TPU lays out the population trainer's
+    u8[100000, 64, 784] so (784 would pad to 896 on the lanes).  What
+    shapes and dtypes the kernel reads, it says itself
+    (``repro.kernels.client_gather.supports``).
+    ``"jnp"`` everywhere else: another layout, a dataset sharded over
+    devices, a CPU, a tracer, whose layout is not known, or an array the
+    kernel does not read.
+    """
+    if (isinstance(data, jax.core.Tracer) or not isinstance(data, jax.Array)
+            or not gather_kernel.supports(data.shape, data.dtype)
+            or len(data.sharding.device_set) != 1
+            or next(iter(data.sharding.device_set)).platform != "tpu"):
+        return "jnp"
+    client_minor = tuple(range(1, data.ndim)) + (0,)
+    return ("pallas" if data.format.layout.major_to_minor == client_minor
+            else "jnp")
+
+
+def gather_backends(client_x, client_y) -> Tuple[str, str]:
+    """``gather_backend`` of the datasets and of the labels: the static
+    ``backends`` argument of ``gather_client_batches``."""
+    return gather_backend(client_x), gather_backend(client_y)
+
+
 def gather_client_batches(
     client_x: jnp.ndarray,         # (N, n, ...) device-resident datasets
     client_y: jnp.ndarray,         # (N, n)
     client_ids: jnp.ndarray,       # (M,) int32
     idx: jnp.ndarray,              # (M, E, B) from client_batch_indices
+    backends: Tuple[str, str] = ("jnp", "jnp"),
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Gather ``(x (M, E, B, ...), y (M, E, B))`` for the scheduled clients.
 
     Only the M scheduled rows of the (N, n, ...) datasets are touched — the
     sparse substrate's per-round data cost is O(M · E · B), independent of
     the total client count N.
+
+    Layout contract: ``backends`` names how the M rows of ``client_x`` and
+    of ``client_y`` are read (``repro.kernels.ops.client_gather``), and the
+    bytes returned are the same either way.  ``"jnp"`` is an XLA gather:
+    on an array stored client-major it reads the rows in place, but on one
+    stored client-minor, inside a loop, XLA first relayouts the whole array
+    to client-major, a copy of every byte in every call.  ``"pallas"`` (or
+    ``"pallas_interpret"``) reads the rows of a client-minor array in place
+    with the ``client_gather`` kernel.  ``gather_backends`` picks them from
+    the arrays' observed layout and placement; inside ``jit`` only the
+    caller that held the concrete arrays can, so it passes them in.  The
+    per-example gather then runs on the small (M, n, ...) rows.
     """
 
     def one(xi, yi, ix):
         return jnp.take(xi, ix, axis=0), jnp.take(yi, ix, axis=0)
 
     return jax.vmap(one)(
-        jnp.take(client_x, client_ids, axis=0),
-        jnp.take(client_y, client_ids, axis=0),
+        ops.client_gather(client_x, client_ids, backends[0]),
+        ops.client_gather(client_y, client_ids, backends[1]),
         idx)
 
 

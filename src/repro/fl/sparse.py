@@ -77,7 +77,11 @@ from repro.core.contribution import (
     update_buffer,
 )
 from repro.core.matching import AdaptiveMatcher, MatcherState, matcher_scores
-from repro.data.pipeline import client_batch_indices, gather_client_batches
+from repro.data.pipeline import (
+    client_batch_indices,
+    gather_backends,
+    gather_client_batches,
+)
 from repro.fl.client import local_sgd
 from repro.fl.round import _FAULT_TAG, dispatch_aggregate, mean_local_loss
 from repro.utils.tree import tree_flatten_concat, tree_unflatten_concat
@@ -245,6 +249,8 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
         client_y: jnp.ndarray,     # (N, n)
         key: jax.Array,
         env: Any = None,
+        *,
+        gather: Tuple[str, str],
     ) -> Tuple[SparseFLState, Dict[str, jnp.ndarray]]:
         cfg = self.cfg
         n, m = cfg.n_clients, cfg.n_sched
@@ -276,7 +282,7 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
         idx = client_batch_indices(k_data, sel, int(client_y.shape[1]),
                                    cfg.local_epochs, cfg.batch_size)
         batches_x, batches_y = gather_client_batches(
-            client_x, client_y, sel, idx)
+            client_x, client_y, sel, idx, gather)
 
         # ---- Steps 1-2: local training for granted clients in S_{t-1} ---
         def one_client(bx, by):
@@ -450,41 +456,47 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
         }
         return new_state, metrics
 
-    @functools.partial(jax.jit, static_argnames=("self",))
-    def _round_jit(self, state, client_x, client_y, key, env):
-        return self._round_impl(state, client_x, client_y, key, env)
+    @functools.partial(jax.jit, static_argnames=("self", "gather"))
+    def _round_jit(self, state, client_x, client_y, key, env, gather):
+        return self._round_impl(state, client_x, client_y, key, env,
+                                gather=gather)
 
     def round(self, state, client_x, client_y, key):
-        return self._round_jit(state, client_x, client_y, key, self.env)
+        return self._round_jit(state, client_x, client_y, key, self.env,
+                               gather_backends(client_x, client_y))
 
     # ------------------------------------------------------------------- run
-    def _run_impl(self, state, client_x, client_y, keys, env=None):
+    def _run_impl(self, state, client_x, client_y, keys, env=None, *,
+                  gather):
         def step(st, k):
-            return self._round_impl(st, client_x, client_y, k, env)
+            return self._round_impl(st, client_x, client_y, k, env,
+                                    gather=gather)
 
         return jax.lax.scan(step, state, keys)
 
     def _run_vmapped(self, states, client_x, client_y, keys,
-                     envs=None, env_axis=None):
+                     envs=None, env_axis=None, *, gather):
         """Seed-batched round scan; client datasets broadcast across seeds.
 
         The one traced program both entry points share (``run`` at batch 1)
         — same bitwise-parity rationale as the dense
-        ``AsyncFLTrainer._run_vmapped``.
+        ``AsyncFLTrainer._run_vmapped``.  The gather kernel takes every
+        seed's scheduled ids in one call over the shared dataset.
         """
         if envs is None:
             envs, env_axis = self.env, None
 
         def one(state, ks, env):
-            return self._run_impl(state, client_x, client_y, ks, env)
+            return self._run_impl(state, client_x, client_y, ks, env,
+                                  gather=gather)
 
         return jax.vmap(one, in_axes=(0, 0, env_axis))(states, keys, envs)
 
-    @functools.partial(jax.jit, static_argnames=("self",))
-    def _run_plain(self, state, client_x, client_y, keys, env):
+    @functools.partial(jax.jit, static_argnames=("self", "gather"))
+    def _run_plain(self, state, client_x, client_y, keys, env, gather):
         lift = functools.partial(jax.tree_util.tree_map, lambda x: x[None])
         out = self._run_vmapped(lift(state), client_x, client_y, keys[None],
-                                envs=env)
+                                envs=env, gather=gather)
         return jax.tree_util.tree_map(lambda x: x[0], out)
 
     def run(
@@ -500,11 +512,21 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
         each round draws its scheduled clients' batches on device from the
         resident (N, n, ...) datasets, so host memory never scales with
         R · N.
+
+        Layout contract: the datasets are read where and as they are
+        stored, and never copied.  A uint8 or int32 array on one TPU stored
+        client-minor (the client axis most minor, as a TPU lays out
+        u8[100000, 64, 784]) has its scheduled rows read in place by the
+        ``client_gather`` kernel; any other layout, a dataset sharded over
+        devices, or a CPU takes XLA's gather.  The choice is read from the
+        arrays here (``repro.data.pipeline.gather_backends``) and compiled
+        in; the bytes each round trains on are the same either way.
         """
-        return self._run_plain(state, client_x, client_y, keys, self.env)
+        return self._run_plain(state, client_x, client_y, keys, self.env,
+                               gather_backends(client_x, client_y))
 
     # ------------------------------------------------- served (SchedServer)
-    def _served_pre_impl(self, state, client_x, client_y, key, env):
+    def _served_pre_impl(self, state, client_x, client_y, key, env, gather):
         """Select + Gather + Steps 1-2 + the Eq.-6 slot carry + channel
         realization — ``_round_impl``'s pre-decision dataflow, verbatim."""
         cfg = self.cfg
@@ -532,7 +554,7 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
         idx = client_batch_indices(k_data, sel, int(client_y.shape[1]),
                                    cfg.local_epochs, cfg.batch_size)
         batches_x, batches_y = gather_client_batches(
-            client_x, client_y, sel, idx)
+            client_x, client_y, sel, idx, gather)
 
         def one_client(bx, by):
             g_tree, loss = local_sgd(self.loss_fn, state.params, bx, by,
@@ -696,12 +718,13 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
         }
         return new_state, metrics
 
-    @functools.partial(jax.jit, static_argnames=("self",))
-    def _served_pre_jit(self, state, client_x, client_y, key, env):
+    @functools.partial(jax.jit, static_argnames=("self", "gather"))
+    def _served_pre_jit(self, state, client_x, client_y, key, env, gather):
         lift = functools.partial(jax.tree_util.tree_map, lambda x: x[None])
 
         def one(s, k):
-            return self._served_pre_impl(s, client_x, client_y, k, env)
+            return self._served_pre_impl(s, client_x, client_y, k, env,
+                                         gather)
 
         out = jax.vmap(one)(lift(state), key[None])
         return jax.tree_util.tree_map(lambda x: x[0], out)
@@ -746,10 +769,12 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
         from repro.sim.serve import ServeRequest   # deferred: sim imports fl
 
         r = int(keys.shape[0])
+        gather = gather_backends(client_x, client_y)
         metrics_rounds = []
         for i in range(r):
             k = keys[i]
-            pre = self._served_pre_jit(state, client_x, client_y, k, self.env)
+            pre = self._served_pre_jit(state, client_x, client_y, k, self.env,
+                                       gather)
             dec = server.serve_decisions([ServeRequest(
                 tenant, rewards=np.asarray(pre.ch_states),
                 key=np.asarray(k), contrib=np.asarray(pre.contrib_sel),

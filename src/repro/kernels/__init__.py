@@ -6,6 +6,8 @@ glr_step           fused streaming GLR detector step: carried prefix-sum
 glr_scan           GLR change-point statistic via full prefix recompute
                    (the legacy reference detector)
 weighted_aggregate fused zeta-weighted masked client aggregation (Eq. 7)
+client_gather      scheduled clients' rows read in place from a dataset
+                   stored client-minor (the population trainer's gather)
 flash_attention    blockwise GQA attention for prefill (dense/MoE/VLM archs)
 
 Each kernel ships with a pure-jnp oracle in ref.py; ops.py holds the jit'd

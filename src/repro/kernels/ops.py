@@ -15,6 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import client_gather as _cg
 from repro.kernels import flash_attention as _fa
 from repro.kernels import glr_scan as _glr
 from repro.kernels import glr_step as _gs
@@ -204,6 +205,38 @@ def robust_trimmed(
             interpret=_interpret(backend, "robust_trimmed"))
     raise ValueError(
         f"robust_trimmed: unknown backend {backend!r}; use one of {_RT_BACKENDS}")
+
+
+_CG_BACKENDS = ("pallas", "pallas_interpret", "jnp")
+
+
+def client_gather(
+    client_x: jnp.ndarray, ids: jnp.ndarray, backend: str = "jnp"
+) -> jnp.ndarray:
+    """Rows ``ids`` (M,) of a per-client array: (N, n, d) or (N, n) ->
+    (M, n, d) or (M, n), the same bytes on every backend.
+
+    The kernel reads a dataset stored client-minor (the client axis on the
+    lanes, as a TPU lays out (N, n, d) uint8 when d pads worse than N) in
+    place, where an XLA gather inside a loop first relayouts all of it.  On
+    any other layout the kernel's transposed view would itself be that
+    copy, and the layout is seen only outside ``jit``, so the kernel is
+    never auto-selected: the caller that holds the array names it
+    (``repro.data.pipeline.gather_backend``).  Backends:
+
+      "jnp"              ``jnp.take`` (``repro.kernels.ref``), the default
+      "pallas"           compiled Pallas kernel (raises off-TPU)
+      "pallas_interpret" Pallas kernel in interpret mode (tests)
+
+    What shapes and dtypes the kernel reads: ``client_gather.supports``.
+    """
+    if backend == "jnp":
+        return ref.client_gather(client_x, ids)
+    if backend in ("pallas", "pallas_interpret"):
+        return _cg.vmappable_client_gather(
+            _interpret(backend, "client_gather"))(client_x, ids)
+    raise ValueError(
+        f"client_gather: unknown backend {backend!r}; use one of {_CG_BACKENDS}")
 
 
 def flash_attention(

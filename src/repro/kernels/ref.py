@@ -223,6 +223,15 @@ def robust_trimmed(updates: jnp.ndarray, mask: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
+# client_gather
+# ---------------------------------------------------------------------------
+
+def client_gather(client_x: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
+    """Rows ``ids`` (M,) of the (N, ...) per-client array: (M, ...)."""
+    return jnp.take(client_x, ids, axis=0)
+
+
+# ---------------------------------------------------------------------------
 # flash_attention
 # ---------------------------------------------------------------------------
 

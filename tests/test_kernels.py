@@ -77,6 +77,8 @@ _PALLAS_CALLS = {
         jnp.asarray(1.0), backend="pallas"),
     "flash_attention": lambda: ops.flash_attention(
         *(jnp.zeros((1, 1, 8, 8)),) * 3, backend="pallas"),
+    "client_gather": lambda: ops.client_gather(
+        jnp.zeros((3, 2, 8), jnp.uint8), jnp.array([0]), backend="pallas"),
 }
 
 
@@ -171,6 +173,63 @@ def test_weighted_aggregate_property(m, p, seed):
     got = ops.weighted_aggregate(upd, sc, backend="pallas_interpret")
     np.testing.assert_allclose(got, ref.weighted_aggregate(upd, sc),
                                rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# client_gather
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape,dtype,ids", [
+    ((300, 4, 16), jnp.uint8, [0, 299, 256, 5, 130]),  # ids in the partial block
+    ((300, 8, 784), jnp.uint8, [299, 0, 257, 128]),    # the trainer's d = 784
+    ((256, 3, 8), jnp.uint8, [255, 0, 127, 128]),      # whole blocks; 3 rows
+    ((100, 5, 16), jnp.uint8, [99, 0, 50]),            # N < 128: all partial
+    ((300, 64), jnp.int32, [0, 299, 1, 200]),          # labels, (N, n)
+    ((300, 9, 24), jnp.int32, [[3, 299], [0, 128]]),   # vmapped over seeds
+    ((300, 2, 8), jnp.uint8, list(range(299, 169, -1))),  # M = 130 > 128
+    ((130, 600, 784), jnp.uint8, [129, 0, 128, 5]),    # n = 600: 8 row tiles
+    ((200, 6, 16384), jnp.uint8, list(range(199, 69, -1))),  # tiles, M > 128
+], ids=["tail", "d784", "whole_blocks", "n_lt_128", "labels", "vmapped",
+        "m_gt_128", "n600_d784", "row_tiles_m_gt_128"])
+def test_client_gather_matches_take(shape, dtype, ids):
+    """The kernel returns exactly the bytes of ``jnp.take`` for every id,
+    the first, the last and those past the last whole 128-client block."""
+    bits = jax.random.bits(KEY, shape, jnp.uint32)
+    x = (bits.astype(jnp.uint8) if dtype == jnp.uint8
+         else jax.lax.bitcast_convert_type(bits, jnp.int32))
+    ids = jnp.asarray(ids, jnp.int32)
+    gather = lambda i: ops.client_gather(x, i, backend="pallas_interpret")
+    got = jax.vmap(gather)(ids) if ids.ndim == 2 else gather(ids)
+    want = jax.vmap(lambda i: ref.client_gather(x, i))(ids) if ids.ndim == 2 \
+        else ref.client_gather(x, ids)
+    assert got.dtype == x.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("shape,dtype,tile,ok", [
+    ((100000, 64, 784), jnp.uint8, 64, True),   # the population trainer
+    ((100000, 64), jnp.int32, 1, True),         # its labels
+    ((10000, 600, 784), jnp.uint8, 75, True),   # McMahan's 600 a client
+    ((100, 601, 784), jnp.uint8, 1, True),      # n prime: a row at a time
+    ((100, 4, 32768), jnp.uint8, 1, True),      # the widest row that fits
+    ((100, 4, 32772), jnp.uint8, 0, False),     # one row over the budget
+    ((100, 4, 6), jnp.uint8, None, False),         # rows not whole words
+    ((100, 4, 8), jnp.float32, None, False),
+    ((100,), jnp.int32, None, False),
+], ids=["fedcnn", "labels", "n600", "n_prime", "widest", "too_wide",
+        "odd_bytes", "float", "1d"])
+def test_client_gather_supports(shape, dtype, tile, ok):
+    """The kernel reads a client's rows in tiles that fit its VMEM budget,
+    whatever the rows a client holds, and says which arrays it cannot
+    read, so that the caller keeps XLA's gather for them."""
+    from repro.kernels import client_gather as cg
+    assert cg.supports(shape, dtype) is ok
+    if tile is not None:
+        n, d = (1, shape[1]) if len(shape) == 2 else shape[1:]
+        assert cg._rows_per_tile(n, d, dtype) == tile
+        tiles = 4 * max(tile, 1) + 4                 # and the temporaries
+        fits = tiles * cg._row_bytes(d, dtype) <= cg.BLOCK_BUDGET_BYTES
+        assert fits is ok
 
 
 # ---------------------------------------------------------------------------

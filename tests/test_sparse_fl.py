@@ -268,3 +268,43 @@ def test_shard_clients_placement_is_bitwise_inert():
     for k in mets_plain:
         np.testing.assert_array_equal(np.asarray(mets_plain[k]),
                                       np.asarray(mets_s[k]))
+
+
+def test_gather_kernel_path_is_bitwise_inert():
+    """Three rounds that read the scheduled clients' uint8 examples and int32
+    labels with the ``client_gather`` kernel (interpret mode) train on the
+    same bytes as XLA's gather: every state leaf and metric equal bitwise.
+    Only clients 0-3 and 294-299 are schedulable, so the rounds read the
+    first client and those past the last whole 128-client block."""
+    n, m, nch, r, d, classes = 300, 8, 6, 3, 16, 4
+    rng = np.random.default_rng(5)
+    cx = jnp.asarray(rng.integers(0, 256, (n, NEX, d), dtype=np.uint8))
+    cy = jnp.asarray(rng.integers(0, classes, (n, NEX), dtype=np.int32))
+
+    def loss(p, x, y):
+        logits = (x.astype(jnp.float32) / 255.0) @ p["w"] + p["b"]
+        return -jnp.mean(jnp.take_along_axis(
+            jax.nn.log_softmax(logits), y[:, None], axis=1))
+
+    params = {"w": jnp.zeros((d, classes), jnp.float32),
+              "b": jnp.zeros((classes,), jnp.float32)}
+    tr = SparseAsyncFLTrainer(
+        SparseFLConfig(n_clients=n, n_sched=m, n_channels=nch, batch_size=B,
+                       local_epochs=E),
+        GLRCUCB(nch, m, history=16),
+        make_stationary(jnp.linspace(0.9, 0.4, nch)), loss)
+    ids = jnp.arange(n)
+    st0 = tr.init(params, KEY)._replace(
+        avail=((ids < 4) | (ids >= n - 6)).astype(jnp.float32))
+    keys = jax.random.split(jax.random.PRNGKey(3), r)
+    st_jnp, mets_jnp = tr.run(st0, cx, cy, keys)
+    st_k, mets_k = tr._run_plain(st0, cx, cy, keys, tr.env,
+                                 ("pallas_interpret", "pallas_interpret"))
+    assert float(jnp.sum(mets_jnp["n_success"])) > 0
+    assert {0, n - 1} <= set(np.asarray(st_jnp.slot_clients).tolist())
+    for la, lb in zip(jax.tree_util.tree_leaves(st_jnp),
+                      jax.tree_util.tree_leaves(st_k)):
+        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+    for k in mets_jnp:
+        np.testing.assert_array_equal(np.asarray(mets_jnp[k]),
+                                      np.asarray(mets_k[k]))

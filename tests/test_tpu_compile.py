@@ -170,3 +170,66 @@ def test_sharded_serve_step_compiles_on_four_chips(topo, no_persistent_cache,
                             NamedSharding(mesh, PartitionSpec("cases")),
                             mesh=mesh)
     assert "tpu_custom_call" in text
+
+
+def _run_plain_text(monkeypatch, sharding, gather):
+    """Compile ``SparseAsyncFLTrainer.run``'s program (a linear classifier,
+    4 of 4,096 clients a round, 2 rounds) for 64 uint8 examples of 784
+    bytes a client, their int32 labels, and the gather ``gather``."""
+    from repro.core.bandits import GLRCUCB
+    from repro.core.channels import make_stationary
+    from repro.fl import SparseAsyncFLTrainer, SparseFLConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(p, x, y):
+        logits = (x.astype(jnp.float32) / 255.0) @ p["w"]
+        return -jnp.mean(jnp.take_along_axis(
+            jax.nn.log_softmax(logits), y[:, None], axis=1))
+
+    tr = SparseAsyncFLTrainer(
+        SparseFLConfig(n_clients=4096, n_sched=4, n_channels=6, batch_size=8,
+                       local_epochs=2),
+        GLRCUCB(6, 4, history=16), make_stationary(jnp.linspace(0.9, 0.4, 6)),
+        loss)
+    sds = functools.partial(jax.tree_util.tree_map,
+                            lambda x: _sds(sharding, x.shape, x.dtype))
+    params = {"w": jnp.zeros((784, 10))}
+    state = sds(jax.eval_shape(tr.init, params, jax.random.PRNGKey(0)))
+    return tr._run_plain.lower(
+        tr, state, _sds(sharding, (4096, 64, 784), jnp.uint8),
+        _sds(sharding, (4096, 64), jnp.int32),
+        _sds(sharding, (2, 2), jnp.uint32), sds(tr.env),
+        (gather, gather)).compile().as_text()
+
+
+@pytest.mark.parametrize("gather,copies", [("jnp", 1), ("pallas", 0)])
+def test_run_reads_client_minor_dataset_in_place(one_chip, no_persistent_cache,
+                                                 monkeypatch, gather, copies):
+    """A TPU stores the (N, 64, 784) uint8 dataset client-minor.  XLA's
+    gather inside the round scan copies all of it to client-major in every
+    call; the ``client_gather`` kernel reads it where it lies."""
+    import re
+    text = _run_plain_text(monkeypatch, one_chip, gather)
+    assert re.search(r"u8\[4096,64,784\]\{0,2,1\S* parameter\(", text)
+    dataset_copies = re.findall(r"= u8\[4096,64,784\]\S* copy\(", text)
+    assert len(dataset_copies) == copies
+    kernels = re.findall(r"%client_gather\S* = \S+ custom-call\(", text)
+    assert len(kernels) == (2 if gather == "pallas" else 0)   # x and y
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((4096, 64, 784), jnp.uint8),      # the population trainer's clients
+    ((4096, 600, 784), jnp.uint8),     # McMahan's 600 examples a client
+    ((4096, 600), jnp.int32),          # their labels
+])
+def test_client_gather_compiles(one_chip, no_persistent_cache, monkeypatch,
+                                shape, dtype):
+    """The gather kernel fits VMEM whatever the examples a client holds: it
+    reads a client's rows in tiles of a fixed budget."""
+    from repro.kernels import ops
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compile_text(lambda x, i: ops.client_gather(x, i, "pallas"),
+                         _sds(one_chip, shape, dtype),
+                         _sds(one_chip, (20,), jnp.int32))
+    assert "tpu_custom_call" in text
