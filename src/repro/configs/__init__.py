@@ -15,6 +15,7 @@ _MODULES: Dict[str, str] = {
     "minicpm3-4b": "repro.configs.minicpm3_4b",
     "hubert-xlarge": "repro.configs.hubert_xlarge",
     "deepseek-v2-236b": "repro.configs.deepseek_v2_236b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
     "mamba2-1.3b": "repro.configs.mamba2_1_3b",
     "qwen3-32b": "repro.configs.qwen3_32b",
     "recurrentgemma-2b": "repro.configs.recurrentgemma_2b",

@@ -12,6 +12,18 @@ from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN context extension (Peng et al., arXiv:2309.00071), as the
+    ``rope_scaling`` block of a DeepSeek-V2 ``config.json`` states it."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     arch_type: str                 # dense | moe | ssm | hybrid | audio | vlm
@@ -29,6 +41,7 @@ class ModelConfig:
     qkv_bias: bool = False         # qwen1.5 / qwen2.5 / phi-3
     qk_norm: bool = False          # qwen3
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[RopeScaling] = None   # None: plain RoPE
     local_attn_window: int = 0     # recurrentgemma local attention
     sliding_window: int = 0        # serve-time ring-cache window for long ctx
                                    # (first-class long_500k option; 0 = full)
@@ -46,8 +59,14 @@ class ModelConfig:
     experts_per_token: int = 0
     d_expert: int = 0              # per-expert FFN width (d_ff for shared path)
     first_k_dense: int = 0         # leading dense layers (deepseek-v2 layer 0)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # GShard capacity per expert; 0: dropless
+                                   # (moe.held_moe_ffn, every routed token kept)
     router_aux_weight: float = 0.001
+    norm_topk_prob: bool = True    # renormalise the top-k router weights
+    routed_scaling_factor: float = 1.0  # scales unrenormalised top-k weights
+    held_first: int = 0            # expert parallelism: this device holds
+    held_count: int = 0            # experts [held_first, held_first +
+                                   # held_count) of n_experts (0: all)
 
     # ---- SSM (mamba-2 / SSD) -------------------------------------------------
     ssm_state: int = 0
@@ -77,12 +96,21 @@ class ModelConfig:
     dtype: str = "bfloat16"
     citation: str = ""
 
+    # ---- LoRA fine-tuning (Hu et al., arXiv:2106.09685) ---------------------
+    lora_rank: int = 0             # adapters on the MLA projections (models.lora)
+    lora_alpha: float = 0.0        # their output is scaled by alpha / rank
+
     # ------------------------------------------------------------------ helpers
     @property
     def resolved_head_dim(self) -> int:
         if self.head_dim:
             return self.head_dim
         return self.d_model // self.n_heads if self.n_heads else 0
+
+    @property
+    def n_held_experts(self) -> int:
+        """Routed experts whose weights live on this device."""
+        return self.held_count or self.n_experts
 
     @property
     def is_decoder(self) -> bool:
@@ -137,7 +165,7 @@ class ModelConfig:
                 continue  # mamba blocks have no separate FFN
             if self.n_experts and i >= self.first_k_dense:
                 fe = self.d_expert or f
-                total += self.n_experts * 3 * d * fe
+                total += self.n_held_experts * 3 * d * fe
                 total += self.n_shared_experts * 3 * d * fe
                 total += d * self.n_experts  # router
             else:

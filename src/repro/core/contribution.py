@@ -89,9 +89,12 @@ def marginal_contribution(
     """C~_m = Gamma_cos(m) * Gamma_err(m)  (Eq. 33).
 
     proxy_loss_fn: maps a flattened parameter vector to the server's proxy
-    loss (Eq. 35).  When None (e.g. at LLM dry-run scale, where a proxy
-    eval per client per round is not deployable), Gamma_err = 1 and the
-    estimator degrades gracefully to the cosine term.
+    loss (Eq. 35), evaluated once per leave-one-out model (vmapped over
+    the M rows).  That is M forwards of the model a round: at LLM scale
+    with LoRA adapters on a frozen base it is deployable, and the
+    benchmark's ``dsv2lite-fedlora`` cell runs it on DeepSeek-V2-Lite's
+    first stage.  When None, Gamma_err = 1 and the estimator degrades
+    gracefully to the cosine term.
     """
     g_loo, p_loo = loo_aggregates(buf, weights)
     gamma_cos = 1.0 - _cosine(buf.grads, g_loo)           # Eq. 34: in [0, 2]

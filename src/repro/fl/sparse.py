@@ -241,6 +241,15 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
         _, idx = jax.lax.top_k(masked, self.cfg.n_sched)
         return jnp.sort(idx).astype(jnp.int32)
 
+    def _bind(self, frozen):
+        """``(loss_fn, proxy_loss_fn)`` with the model's frozen weights bound
+        as their last argument (``run``'s ``frozen`` operand)."""
+        if frozen is None:
+            return self.loss_fn, self.proxy_loss_fn
+        proxy = self.proxy_loss_fn
+        return (lambda p, x, y: self.loss_fn(p, x, y, frozen),
+                None if proxy is None else (lambda flat: proxy(flat, frozen)))
+
     # ----------------------------------------------------------------- round
     def _round_impl(
         self,
@@ -249,6 +258,7 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
         client_y: jnp.ndarray,     # (N, n)
         key: jax.Array,
         env: Any = None,
+        frozen: Any = None,
         *,
         gather: Tuple[str, str],
     ) -> Tuple[SparseFLState, Dict[str, jnp.ndarray]]:
@@ -256,6 +266,7 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
         n, m = cfg.n_clients, cfg.n_sched
         if env is None:
             env = self.env
+        loss_fn, proxy_loss_fn = self._bind(frozen)
         k_env, k_sel = jax.random.split(key)
         t = state.t
 
@@ -286,11 +297,12 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
 
         # ---- Steps 1-2: local training for granted clients in S_{t-1} ---
         def one_client(bx, by):
-            g_tree, loss = local_sgd(self.loss_fn, state.params, bx, by,
-                                     cfg.client_lr)
-            return tree_flatten_concat(g_tree), loss
+            g_tree, *rest = local_sgd(loss_fn, state.params, bx, by,
+                                      cfg.client_lr)
+            return (tree_flatten_concat(g_tree), *rest)
 
-        fresh_updates, local_losses = jax.vmap(one_client)(batches_x, batches_y)
+        fresh_updates, local_losses, *counters = jax.vmap(one_client)(
+            batches_x, batches_y)
 
         if self.faults is not None:
             k_fault = jax.random.fold_in(key, _FAULT_TAG)
@@ -384,7 +396,7 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
             carried_cb, agg_mask > 0.5, agg_buffers,
             jnp.broadcast_to(params_flat, buffers.shape))
         contrib_rows = marginal_contribution(contrib_buf, zeta,
-                                             self.proxy_loss_fn)
+                                             proxy_loss_fn)
         zeta_rows = aggregation_weights(contrib_rows)
 
         # ---- Scatter: per-client scalars + slot ownership turnover ------
@@ -454,6 +466,9 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
             "n_evicted": jnp.sum(evicted.astype(jnp.float32)),
             "n_available": jnp.sum(state.avail),
         }
+        for c in counters:
+            metrics.update(jax.tree_util.tree_map(
+                lambda a: jnp.sum(a, axis=0), c))
         return new_state, metrics
 
     @functools.partial(jax.jit, static_argnames=("self", "gather"))
@@ -466,16 +481,16 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
                                gather_backends(client_x, client_y))
 
     # ------------------------------------------------------------------- run
-    def _run_impl(self, state, client_x, client_y, keys, env=None, *,
-                  gather):
+    def _run_impl(self, state, client_x, client_y, keys, env=None,
+                  frozen=None, *, gather):
         def step(st, k):
-            return self._round_impl(st, client_x, client_y, k, env,
+            return self._round_impl(st, client_x, client_y, k, env, frozen,
                                     gather=gather)
 
         return jax.lax.scan(step, state, keys)
 
     def _run_vmapped(self, states, client_x, client_y, keys,
-                     envs=None, env_axis=None, *, gather):
+                     envs=None, env_axis=None, frozen=None, *, gather):
         """Seed-batched round scan; client datasets broadcast across seeds.
 
         The one traced program both entry points share (``run`` at batch 1)
@@ -487,16 +502,17 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
             envs, env_axis = self.env, None
 
         def one(state, ks, env):
-            return self._run_impl(state, client_x, client_y, ks, env,
+            return self._run_impl(state, client_x, client_y, ks, env, frozen,
                                   gather=gather)
 
         return jax.vmap(one, in_axes=(0, 0, env_axis))(states, keys, envs)
 
     @functools.partial(jax.jit, static_argnames=("self", "gather"))
-    def _run_plain(self, state, client_x, client_y, keys, env, gather):
+    def _run_plain(self, state, client_x, client_y, keys, env, gather,
+                   frozen=None):
         lift = functools.partial(jax.tree_util.tree_map, lambda x: x[None])
         out = self._run_vmapped(lift(state), client_x, client_y, keys[None],
-                                envs=env, gather=gather)
+                                envs=env, frozen=frozen, gather=gather)
         return jax.tree_util.tree_map(lambda x: x[0], out)
 
     def run(
@@ -505,6 +521,7 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
         client_x: jnp.ndarray,     # (N, n, ...) full per-client datasets
         client_y: jnp.ndarray,     # (N, n)
         keys: jnp.ndarray,         # (R,) per-round PRNG keys
+        frozen: Any = None,        # the model's frozen weights, if any
     ) -> Tuple[SparseFLState, Dict[str, jnp.ndarray]]:
         """Fuse R sparse FL rounds into one ``lax.scan`` XLA program.
 
@@ -521,9 +538,19 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
         devices, or a CPU takes XLA's gather.  The choice is read from the
         arrays here (``repro.data.pipeline.gather_backends``) and compiled
         in; the bytes each round trains on are the same either way.
+
+        ``frozen``: a pytree of weights the model reads and nobody trains,
+        such as the base of LoRA fine-tuning, where ``state.params`` holds
+        the adapters.  It is an operand of the compiled program and is
+        bound inside the trace: the local steps call ``loss_fn(params, x,
+        y, frozen)`` and the contribution estimate ``proxy_loss_fn(flat,
+        frozen)``.  Closed over instead, JAX would embed the arrays in the
+        executable as constants.  With ``frozen=None`` (the default) the
+        two take their usual arguments and the program is the one
+        compiled without the operand.
         """
         return self._run_plain(state, client_x, client_y, keys, self.env,
-                               gather_backends(client_x, client_y))
+                               gather_backends(client_x, client_y), frozen)
 
     # ------------------------------------------------- served (SchedServer)
     def _served_pre_impl(self, state, client_x, client_y, key, env, gather):
@@ -558,7 +585,7 @@ class SparseAsyncFLTrainer:                    # trainer (env holds arrays)
 
         def one_client(bx, by):
             g_tree, loss = local_sgd(self.loss_fn, state.params, bx, by,
-                                     cfg.client_lr)
+                                     cfg.client_lr)[:2]
             return tree_flatten_concat(g_tree), loss
 
         fresh_updates, local_losses = jax.vmap(one_client)(batches_x, batches_y)

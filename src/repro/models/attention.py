@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import ParamBuilder, apply_rope, rms_norm
+from repro.models import lora
+from repro.models.layers import ParamBuilder, apply_rope, rms_norm, yarn_get_mscale
 from repro.models.kvcache import ring_slot, valid_mask
 
 _NEG_INF = -1e30
@@ -202,8 +203,10 @@ def _project_qkv(p, prefix, x, cfg: ModelConfig, positions):
         q = rms_norm(q, p[f"{prefix}/q_norm"], cfg.norm_eps)
         k = rms_norm(k, p[f"{prefix}/k_norm"], cfg.norm_eps)
     if cfg.is_decoder:  # encoders (hubert) use absolute conv positions, no rope
-        q = apply_rope(q.transpose(0, 2, 1, 3), positions, cfg.rope_theta).transpose(0, 2, 1, 3)
-        k = apply_rope(k.transpose(0, 2, 1, 3), positions, cfg.rope_theta).transpose(0, 2, 1, 3)
+        q = apply_rope(q.transpose(0, 2, 1, 3), positions, cfg.rope_theta,
+                       cfg.rope_scaling).transpose(0, 2, 1, 3)
+        k = apply_rope(k.transpose(0, 2, 1, 3), positions, cfg.rope_theta,
+                       cfg.rope_scaling).transpose(0, 2, 1, 3)
     return q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
 
@@ -251,6 +254,16 @@ def gqa_decode(
 # MLA block (deepseek-v2 / minicpm3)
 # ---------------------------------------------------------------------------
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(qk_nope + qk_rope)^-0.5, times YaRN's mscale(factor, mscale_all_dim)^2
+    where the config scales its rope (DeepSeek-V2's ``softmax_scale``)."""
+    scale = 1.0 / ((cfg.qk_nope_dim + cfg.qk_rope_dim) ** 0.5)
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_get_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_q(p, prefix, x, cfg: ModelConfig, positions):
     b, s, _ = x.shape
     h, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
@@ -259,18 +272,20 @@ def _mla_q(p, prefix, x, cfg: ModelConfig, positions):
         ql = rms_norm(ql, p[f"{prefix}/q_norm"], cfg.norm_eps)
         q = jnp.einsum("bsr,rh->bsh", ql, p[f"{prefix}/wq_up"])
     else:
-        q = jnp.einsum("bsd,dh->bsh", x, p[f"{prefix}/wq"])
+        q = lora.project(p, f"{prefix}/wq", x, cfg)
     q = q.reshape(b, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope.transpose(0, 2, 1, 3), positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope.transpose(0, 2, 1, 3), positions, cfg.rope_theta,
+                        cfg.rope_scaling)
     return q_nope.transpose(0, 2, 1, 3), q_rope  # (B,H,S,dn), (B,H,S,dr)
 
 
 def _mla_latent(p, prefix, x, cfg: ModelConfig, positions):
     r_kv, dr = cfg.kv_lora_rank, cfg.qk_rope_dim
-    kv = jnp.einsum("bsd,dr->bsr", x, p[f"{prefix}/wkv_down"])
+    kv = lora.project(p, f"{prefix}/wkv_down", x, cfg)
     latent = rms_norm(kv[..., :r_kv], p[f"{prefix}/kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(kv[..., r_kv:], positions, cfg.rope_theta)  # (B,S,dr) shared
+    k_rope = apply_rope(kv[..., r_kv:], positions, cfg.rope_theta,
+                        cfg.rope_scaling)                   # (B,S,dr) shared
     return latent, k_rope
 
 
@@ -283,16 +298,16 @@ def mla_prefill(
     positions = jnp.arange(s)
     q_nope, q_rope = _mla_q(p, prefix, x, cfg, positions)
     latent, k_rope = _mla_latent(p, prefix, x, cfg, positions)
-    kv = jnp.einsum("bsr,rh->bsh", latent, p[f"{prefix}/wkv_up"]).reshape(b, s, h, dn + dv)
+    kv = lora.project(p, f"{prefix}/wkv_up", latent, cfg).reshape(b, s, h, dn + dv)
     k_nope = kv[..., :dn].transpose(0, 2, 1, 3)
     v = kv[..., dn:].transpose(0, 2, 1, 3)
     # fold the shared rotary key into per-head keys; concatenate nope|rope dims
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, None], (b, h, s, q_rope.shape[-1]))], axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = 1.0 / ((dn + cfg.qk_rope_dim) ** 0.5)
-    out = attn_core(q, k, v, causal=True, window=window, scale=scale)
+    out = attn_core(q, k, v, causal=True, window=window,
+                    scale=mla_softmax_scale(cfg))
     out = out.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
-    return jnp.einsum("bsh,hd->bsd", out, p[f"{prefix}/wo"])
+    return lora.project(p, f"{prefix}/wo", out, cfg)
 
 
 def mla_decode(
@@ -320,9 +335,9 @@ def mla_decode(
     cache_krope = jax.lax.dynamic_update_slice(
         cache_krope, krope_new.astype(cache_krope.dtype), (0, slot, 0))
 
-    w_up = p[f"{prefix}/wkv_up"].reshape(r_kv, h, dn + dv)
+    w_up = lora.weight(p, f"{prefix}/wkv_up", cfg).reshape(r_kv, h, dn + dv)
     w_uk, w_uv = w_up[..., :dn], w_up[..., dn:]
-    scale = 1.0 / ((dn + dr) ** 0.5)
+    scale = mla_softmax_scale(cfg)
     lat = cache_latent.astype(jnp.float32)                  # (B,P,r)
     if absorb:
         # q_eff[b,h,r] = sum_dn q_nope[b,h,dn] * w_uk[r,h,dn]
@@ -344,5 +359,5 @@ def mla_decode(
         v = jnp.einsum("bpr,rhd->bhpd", lat, w_uv.astype(jnp.float32))
         out = jnp.einsum("bhp,bhpd->bhd", probs, v)
     out = out.reshape(b, 1, h * dv).astype(x.dtype)
-    y = jnp.einsum("bsh,hd->bsd", out, p[f"{prefix}/wo"])
+    y = jnp.einsum("bsh,hd->bsd", out, lora.weight(p, f"{prefix}/wo", cfg))
     return y, cache_latent, cache_krope

@@ -20,6 +20,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 class ParamBuilder:
@@ -106,12 +107,61 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., S, D_rot) with positions (..., S) or (S,).  Pairs (2i, 2i+1)."""
+def yarn_get_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention temperature factor, 0.1 * mscale * ln(scale) + 1."""
+    if scale <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def _yarn_correction_dim(rotations: float, dim: int, theta: float,
+                         max_pos: int) -> float:
+    return (dim * math.log(max_pos / (rotations * 2 * math.pi))) / (
+        2 * math.log(theta))
+
+
+def yarn_freqs(head_dim: int, theta: float, scaling) -> np.ndarray:
+    """YaRN inverse frequencies (head_dim // 2,), as DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding`` computes them: the dims that turn
+    fewer than ``beta_slow`` times over the original context are
+    interpolated (divided by ``factor``), those that turn more than
+    ``beta_fast`` times keep their frequency, and a linear ramp between the
+    two correction dims blends them."""
+    base = theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim)
+    extra, inter = 1.0 / base, 1.0 / (scaling.factor * base)
+    n = scaling.original_max_position_embeddings
+    low = max(math.floor(_yarn_correction_dim(scaling.beta_fast, head_dim,
+                                              theta, n)), 0)
+    high = min(math.ceil(_yarn_correction_dim(scaling.beta_slow, head_dim,
+                                              theta, n)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                     # 1: extrapolate, 0: interpolate
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling=None) -> jnp.ndarray:
+    """x: (..., S, D_rot) with positions (..., S) or (S,).  Pairs (2i, 2i+1).
+
+    ``scaling`` (a ``RopeScaling``) turns on YaRN: its inverse frequencies,
+    and cos/sin scaled by mscale / mscale_all_dim (1 where the two agree).
+    DeepSeek-V2 de-interleaves each head's rotary dims before rotating
+    halves; rotating the interleaved pairs in place gives the same q.k
+    products (both sides see the same permutation)."""
     d = x.shape[-1]
-    inv = rope_freqs(d, theta)
+    if scaling is None:
+        inv, m = rope_freqs(d, theta), 1.0
+    else:
+        inv = jnp.asarray(yarn_freqs(d, theta, scaling))
+        m = (yarn_get_mscale(scaling.factor, scaling.mscale)
+             / yarn_get_mscale(scaling.factor, scaling.mscale_all_dim))
     ang = positions[..., None].astype(jnp.float32) * inv  # (..., S, D/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if m != 1.0:
+        cos, sin = cos * m, sin * m
     x1 = x[..., 0::2].astype(jnp.float32)
     x2 = x[..., 1::2].astype(jnp.float32)
     out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)

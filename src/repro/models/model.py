@@ -126,7 +126,8 @@ class Model:
 
         ``last_only`` unembeds just the final position — the serving-prefill
         path, which avoids materializing (B, S, V) logits at 32k context."""
-        x, aux = self._forward_hidden(params, batch)
+        x, stats = self._forward_hidden(params, batch)
+        aux = stats["balance"]
         if last_only:
             x = x[:, -1:]
         x = constrain(x, "batch", None, None)
@@ -139,18 +140,23 @@ class Model:
 
     def _forward_hidden(
         self, params: Params, batch: Dict[str, jnp.ndarray]
-    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """All blocks + final norm; returns (hidden (B,S,d), moe_aux)."""
+    ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+        """All blocks + final norm; returns (hidden (B,S,d), stats summed
+        over the layers: ``balance``, the MoE load-balance loss, and the
+        dropless MoE layers' counters)."""
         cfg = self.cfg
         x = self._embed_inputs(params, batch)
-        aux = jnp.zeros((), jnp.float32)
+        aux: Dict[str, jnp.ndarray] = {}
+
+        def add(a, b):
+            return {k: a.get(k, 0) + b.get(k, 0) for k in {**a, **b}}
 
         for i in self._unrolled():
             kind = cfg.layer_kind(i)
             window = cfg.local_attn_window if kind == "attn" else 0
             sub = _subtree(params, f"layers/{i:02d}")
             x, a = block_forward(sub, "b", x, cfg, kind, _ffn_is_moe(cfg, i), window)
-            aux = aux + a
+            aux = add(aux, a)
 
         n_scan = self._scanned_layers()
         if n_scan:
@@ -161,7 +167,7 @@ class Model:
             x, a = scanned_forward(
                 stacked, x, cfg, kind, _ffn_is_moe(cfg, i0), window, self.remat,
                 seq_shard=self.seq_shard)
-            aux = aux + a
+            aux = add(aux, a)
 
         return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
 
@@ -169,22 +175,35 @@ class Model:
     def loss(
         self, params: Params, batch: Dict[str, jnp.ndarray],
         example_weights: Optional[jnp.ndarray] = None,
+        adapters: Optional[Params] = None,
     ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         """Mean loss + metrics.  ``example_weights`` (B,) scales per-example
         loss — this is how the FL round folds the transmission mask and the
-        zeta aggregation weights (Eq. 7) into one backward pass."""
-        cfg = self.cfg
-        hidden, aux = self._forward_hidden(params, batch)
-        w_out = self._unembed_matrix(params)
+        zeta aggregation weights (Eq. 7) into one backward pass.
 
+        ``adapters`` (``repro.models.lora``) are trainable LoRA factors on a
+        frozen base ``params``: differentiate with respect to them alone.
+        With dropless MoE layers (``capacity_factor`` 0) the metrics also
+        carry their counters, summed over layers (``moe_routed`` per held
+        expert, ``moe_absent``, ``moe_dropped``)."""
+        cfg = self.cfg
+        if adapters is not None:
+            params = {**params, **adapters}
         if cfg.arch_type == "audio":
+            hidden, stats = self._forward_hidden(params, batch)
             hid, labels = hidden, batch["labels"]
         else:
+            # predict token t+1 from position (offset + t); the last token is
+            # only a label, so the T - 1 positions that predict run (causal:
+            # their hidden states are those of the whole sequence)
             tokens = batch["tokens"]
+            hidden, stats = self._forward_hidden(
+                params, {**batch, "tokens": tokens[:, :-1]})
             offset = cfg.frontend_tokens if cfg.arch_type == "vlm" else 0
-            # predict token t+1 from position (offset + t)
-            hid = hidden[:, offset : offset + tokens.shape[1] - 1]
+            hid = hidden[:, offset:]
             labels = tokens[:, 1:]
+        aux = stats["balance"]
+        w_out = self._unembed_matrix(params)
 
         nll = self._nll(hid, w_out, labels)            # (B, T)
 
@@ -196,8 +215,10 @@ class Model:
 
         w = example_weights if example_weights is not None else jnp.ones_like(per_example)
         loss = jnp.sum(per_example * w) / jnp.maximum(jnp.sum(w), 1e-9)
-        total = loss + cfg.router_aux_weight * aux
-        return total, {"loss": loss, "moe_aux": aux, "per_example": per_example}
+        total = loss + cfg.router_aux_weight * aux if cfg.router_aux_weight else loss
+        metrics = {"loss": loss, "moe_aux": aux, "per_example": per_example}
+        metrics.update({f"moe_{k}": v for k, v in stats.items() if k != "balance"})
+        return total, metrics
 
     def _nll(self, hid: jnp.ndarray, w_out: jnp.ndarray,
              labels: jnp.ndarray) -> jnp.ndarray:

@@ -60,12 +60,23 @@ def add_block_params(
         add_mlp_params(pb, f"{prefix}/mlp", d, cfg.d_ff, cfg.mlp_act, stacked)
 
 
+def moe_block_ffn(p, prefix: str, x: jnp.ndarray, cfg: ModelConfig):
+    """A block's MoE FFN: the dropless held-experts layer where the config
+    sets no capacity, else the capacity-bound one.  Returns (out, stats):
+    the load-balance loss as ``balance`` and the dropless layer's counters."""
+    if cfg.capacity_factor == 0:
+        return moe_mod.held_moe_ffn(p, prefix, x, cfg)
+    out, aux = moe_mod.moe_ffn(p, prefix, x, cfg)
+    return out, {"balance": aux}
+
+
 def block_forward(
     p: Dict[str, jnp.ndarray], prefix: str, x: jnp.ndarray, cfg: ModelConfig,
     kind: str, moe_ffn: bool, window: int = 0,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Full-sequence block.  Returns (x, moe_aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Full-sequence block.  Returns (x, stats): ``balance`` (the MoE
+    load-balance loss, 0 without experts) and the MoE layer's counters."""
+    aux = {"balance": jnp.zeros((), jnp.float32)}
     h = rms_norm(x, p[f"{prefix}/norm1"], cfg.norm_eps)
     if kind == "attn":
         if cfg.attention == "mla":
@@ -80,7 +91,7 @@ def block_forward(
     x = x + h
     h = rms_norm(x, p[f"{prefix}/norm2"], cfg.norm_eps)
     if moe_ffn:
-        h, aux = moe_mod.moe_ffn(p, f"{prefix}/moe", h, cfg)
+        h, aux = moe_block_ffn(p, f"{prefix}/moe", h, cfg)
     else:
         h = apply_mlp(p, f"{prefix}/mlp", h, cfg.mlp_act)
     return x + h, aux
@@ -113,7 +124,7 @@ def block_decode(
     x = x + h
     h = rms_norm(x, p[f"{prefix}/norm2"], cfg.norm_eps)
     if moe_ffn:
-        h, _ = moe_mod.moe_ffn(p, f"{prefix}/moe", h, cfg)
+        h, _ = moe_block_ffn(p, f"{prefix}/moe", h, cfg)
     else:
         h = apply_mlp(p, f"{prefix}/mlp", h, cfg.mlp_act)
     return x + h, new_cache
@@ -141,8 +152,9 @@ def scanned_forward(
     stacked: Dict[str, jnp.ndarray], x: jnp.ndarray, cfg: ModelConfig,
     kind: str, moe_ffn: bool, window: int = 0, remat: str = "full",
     seq_shard: bool = False,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Scan a homogeneous block stack.  ``stacked`` values have leading L dim.
+    Returns (x, stats summed over the layers).
 
     ``seq_shard``: shard the residual stream over the tensor axis on the
     sequence dim between blocks (Megatron sequence parallelism) — the
@@ -156,7 +168,7 @@ def scanned_forward(
 
     body = _remat(body, remat)
     x, auxs = jax.lax.scan(body, x, stacked)
-    return x, jnp.sum(auxs)
+    return x, jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), auxs)
 
 
 def scanned_decode(

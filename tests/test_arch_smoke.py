@@ -1,6 +1,6 @@
 """Per-architecture smoke tests (deliverable f).
 
-For each of the 10 assigned architectures: instantiate the REDUCED
+For each of the 11 assigned architectures: instantiate the REDUCED
 variant of the same family (<=2-3 layers, d_model<=512, <=4 experts),
 run one forward + one train step on CPU, assert output shapes and no
 NaNs; decoders additionally run one serve step.
@@ -34,7 +34,7 @@ def _batch_for(cfg):
 
 
 def test_all_archs_have_smoke_configs():
-    assert len(list_archs()) == 10
+    assert len(list_archs()) == 11
 
 
 @pytest.mark.parametrize("arch", list_archs())

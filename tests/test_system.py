@@ -91,4 +91,4 @@ def test_input_specs_cover_all_arch_shape_pairs():
                 assert "pos" in cs
             n_ok += 1
     assert n_skip == 2                      # hubert x {decode_32k, long_500k}
-    assert n_ok == 38
+    assert n_ok == 42                       # 11 archs x 4 shapes - 2
